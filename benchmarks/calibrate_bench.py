@@ -216,10 +216,11 @@ def validate(record: dict) -> None:
 
 def run_bench(smoke: bool) -> dict:
     from repro.core.tech import StaticCostSource
+    from repro.kernels import default_interpret
     from repro.match import calibrate
 
     cfg = SMOKE if smoke else FULL
-    interpret = calibrate.default_interpret()
+    interpret = default_interpret()
     if smoke:
         # Self-contained on any runner: fast in-process autotune, no
         # table I/O (the committed table may describe other hardware).

@@ -166,7 +166,7 @@ def validate(record: dict) -> None:
 
 
 def run_bench(smoke: bool) -> dict:
-    from repro.match import engine as _engine
+    from repro.kernels import default_interpret
 
     cfg = SMOKE if smoke else FULL
     rng = np.random.default_rng(11)
@@ -176,7 +176,7 @@ def run_bench(smoke: bool) -> dict:
         "shape": {k: cfg[k] for k in
                   ("R0", "F", "P", "n_docs", "ingest_batch", "q_per_tick")},
         **bench_provenance(),
-        "interpret": _engine.default_interpret(),
+        "interpret": default_interpret(),
         "smoke": smoke,
         "results": results,
     }

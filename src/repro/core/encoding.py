@@ -117,15 +117,17 @@ def pack_codes_u32(codes: np.ndarray, bits: int = DNA_BITS) -> np.ndarray:
     Characters are packed LSB-first: char i occupies bits [i*bits, (i+1)*bits)
     of word i // cpw.  Tail lanes are zero-padded (caller masks them).
     """
-    codes = np.asarray(codes, np.uint32)
+    codes = np.asarray(codes)
     cpw = 32 // bits
     n = codes.shape[-1]
     n_words = -(-n // cpw)
-    padded = np.zeros(codes.shape[:-1] + (n_words * cpw,), np.uint32)
-    padded[..., :n] = codes
-    lanes = padded.reshape(padded.shape[:-1] + (n_words, cpw))
-    shifts = (np.arange(cpw, dtype=np.uint32) * bits).astype(np.uint32)
-    return (lanes << shifts).sum(-1, dtype=np.uint64).astype(np.uint32)
+    # One strided pass per lane position: no full-width uint32/uint64
+    # temporaries, so a genome-scale corpus packs in bounded memory.
+    words = np.zeros(codes.shape[:-1] + (n_words,), np.uint32)
+    for i in range(min(cpw, n)):
+        lane = codes[..., i::cpw].astype(np.uint32)
+        words[..., :lane.shape[-1]] |= lane << np.uint32(i * bits)
+    return words
 
 
 def unpack_codes_u32(words: np.ndarray, n: int, bits: int = DNA_BITS) -> np.ndarray:

@@ -13,3 +13,14 @@ engine layer ``repro.match`` (planner + device-resident packed corpus +
 streaming executor; DESIGN.md Sec. 3); ``ops`` keeps thin one-shot compat
 wrappers plus the bulk-op entry points.
 """
+
+
+def default_interpret() -> bool:
+    """Whether Pallas kernels run in interpret mode by default.
+
+    The one switch for the whole stack: compiled Mosaic on a TPU, the
+    Pallas interpreter anywhere else (how CPU test runs execute the kernel
+    bodies).  Every ``interpret=None`` argument resolves through here.
+    """
+    import jax
+    return jax.default_backend() != "tpu"

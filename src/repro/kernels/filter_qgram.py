@@ -45,7 +45,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 from repro.kernels.popcount import popcount_words
-from repro.kernels.tiling import coarse_row_tile
+from repro.kernels.tiling import coarse_row_tile, lane_bytes
 
 FILTER_ROW_TILE = 128
 
@@ -77,7 +77,7 @@ def filter_qgram(row_sigs: jnp.ndarray, qsig: jnp.ndarray, *, slack: int,
         raise ValueError(f"qsig must be (1, {Wb}); got {qsig.shape}")
     # Row-elementwise body: coarsen the dispatch tile (kernels.tiling) so
     # launch overhead amortizes at scale; output is bit-identical.
-    tile = coarse_row_tile(R, FILTER_ROW_TILE, (Wb + 1) * 4)
+    tile = coarse_row_tile(R, FILTER_ROW_TILE, lane_bytes(Wb, 1))
     grid = (R // tile,)
     kernel = functools.partial(_filter_kernel, slack=int(slack))
     return pl.pallas_call(
@@ -118,13 +118,20 @@ def filter_qgram_ref(row_sigs: np.ndarray, qsig: np.ndarray,
 # recompile per distinct value).
 
 def _bank_kernel(psig_ref, dsig_ref, slack_ref, out_ref):
-    psigs = psig_ref[...]                    # (TILE, Wb) required bits
+    # Patterns ride the lanes, documents the sublanes: every vector op is
+    # a lane-dense (D, TILE) block, and the per-word loop keeps the
+    # (TILE, D, Wb) broadcast (8 of 128 lanes used) out of VMEM.
+    psigs = psig_ref[...].T                  # (Wb, TILE) required bits
     dsigs = dsig_ref[...]                    # (D, Wb) doc occurrence sigs
-    slacks = slack_ref[...]                  # (TILE, 1) per-pattern budget
-    absent = popcount_words(
-        psigs[:, None, :] & ~dsigs[None, :, :]).sum(axis=-1)  # (TILE, D)
-    out_ref[...] = (absent <= slacks).any(axis=1,
-                                          keepdims=True).astype(jnp.int32)
+    slacks = slack_ref[...]                  # (1, TILE) per-pattern budget
+    shape = (dsigs.shape[0], psigs.shape[1])
+    absent = jnp.zeros(shape, jnp.int32)
+    for w in range(psigs.shape[0]):
+        absent += popcount_words(jnp.broadcast_to(psigs[w:w + 1], shape)
+                                 & ~jnp.broadcast_to(dsigs[:, w:w + 1],
+                                                     shape))
+    fires = (absent <= jnp.broadcast_to(slacks, shape)).astype(jnp.int32)
+    out_ref[...] = jnp.max(fires, axis=0, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -153,22 +160,23 @@ def bank_prefilter(pat_sigs: jnp.ndarray, doc_sigs: jnp.ndarray,
                          f"{doc_sigs.shape}")
     if slacks.shape != (Q, 1):
         raise ValueError(f"slacks must be ({Q}, 1); got {slacks.shape}")
-    # Per-pattern-row footprint includes the (TILE, D, Wb) popcount
-    # temporary, so the coarsening budget sees D * Wb words per row.
-    tile = coarse_row_tile(Q, FILTER_ROW_TILE, (Wb * (D + 1) + D + 2) * 4)
-    grid = (Q // tile,)
-    return pl.pallas_call(
+    # Per-pattern footprint: the lane-padded signature row plus a column
+    # of each (D, TILE) temporary (absent counts and popcount stages).
+    tile = coarse_row_tile(Q, FILTER_ROW_TILE,
+                           lane_bytes(Wb) + 8 * 4 * (D + 2))
+    out = pl.pallas_call(
         _bank_kernel,
-        grid=grid,
+        grid=(Q // tile,),
         in_specs=[
             pl.BlockSpec((tile, Wb), lambda i: (i, 0)),
             pl.BlockSpec((D, Wb), lambda i: (0, 0)),
-            pl.BlockSpec((tile, 1), lambda i: (i, 0)),
+            pl.BlockSpec((1, tile), lambda i: (0, i)),
         ],
-        out_specs=pl.BlockSpec((tile, 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((Q, 1), jnp.int32),
+        out_specs=pl.BlockSpec((1, tile), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, Q), jnp.int32),
         interpret=interpret,
-    )(pat_sigs, doc_sigs, slacks)
+    )(pat_sigs, doc_sigs, slacks.reshape(1, Q))
+    return out.reshape(Q, 1)
 
 
 def bank_prefilter_ref(pat_sigs: np.ndarray, doc_sigs: np.ndarray,

@@ -19,16 +19,12 @@ import warnings
 
 from typing import Literal, Optional
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
 from . import bitwise as _bitwise
+from . import default_interpret
 from . import popcount as _popcount
-
-
-def default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _pad_rows(x: np.ndarray, mult: int) -> np.ndarray:

@@ -109,13 +109,6 @@ def initialize(info: Optional[HostInfo] = None) -> HostInfo:
             num_processes=info.process_count,
             process_id=info.process_id,
         )
-        try:
-            # Non-shard_map ops over globally-sharded arrays (jitted
-            # splices with replicated operands) are SPMD-legal here;
-            # older jax versions gate them behind spmd_mode.
-            jax.config.update("jax_spmd_mode", "allow_all")
-        except Exception:
-            pass
     return info
 
 

@@ -2,12 +2,24 @@
 
 A function, not a module-level constant, so importing this module never
 touches jax device state.
+
+Every mesh here has ``Auto`` axes: the sharded match stack places arrays
+with explicit ``NamedSharding``s and ``shard_map``, and lets XLA propagate
+the rest.  ``jax.make_mesh`` defaults to ``Explicit`` axes, under which
+sharding-typed ops (e.g. a jitted ``jnp.take`` over a row-sharded array)
+demand an ``out_sharding`` at every call site.
 """
 
 from __future__ import annotations
 
 import jax
 import numpy as np
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes, devices):
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -22,7 +34,7 @@ def make_production_mesh(*, multi_pod: bool = False):
         raise RuntimeError(
             f"need {n} devices for mesh {shape}, have {len(devices)} -- "
             "run under launch/dryrun.py which forces 512 host devices")
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+    return _auto_mesh(shape, axes, devices[:n])
 
 
 def make_debug_mesh(n_data: int = 2, n_model: int = 2, multi_pod: bool = False):
@@ -30,7 +42,7 @@ def make_debug_mesh(n_data: int = 2, n_model: int = 2, multi_pod: bool = False):
     shape = ((2, n_data, n_model) if multi_pod else (n_data, n_model))
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     n = int(np.prod(shape))
-    return jax.make_mesh(shape, axes, devices=jax.devices()[:n])
+    return _auto_mesh(shape, axes, jax.devices()[:n])
 
 
 def make_row_mesh(n_shards: int):
@@ -47,4 +59,4 @@ def make_row_mesh(n_shards: int):
             f"need {n_shards} devices for a {n_shards}-shard row mesh, "
             f"have {len(devices)} -- force host devices via XLA_FLAGS="
             f"--xla_force_host_platform_device_count=N")
-    return jax.make_mesh((n_shards,), ("data",), devices=devices[:n_shards])
+    return _auto_mesh((n_shards,), ("data",), devices[:n_shards])

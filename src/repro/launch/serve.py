@@ -136,7 +136,15 @@ def run_match_service(args) -> None:
                 _print_metrics(svc, f"tick={svc.stats.n_ticks}")
     svc.flush()
     dt = time.perf_counter() - t0
-    assert all(t.done for t in tickets) and all(t.done for t in ingests)
+    # The service isolates a failing group on its tickets; the launcher
+    # must not: one refused kernel or lost result fails the run.
+    bad = [t for t in tickets
+           if not t.done or t.error is not None or t.result is None]
+    if bad or not all(t.done for t in ingests):
+        first = next((t.error for t in bad if t.error is not None), None)
+        raise RuntimeError(
+            f"{len(bad)} of {len(tickets)} match queries failed or returned "
+            f"no result; first error: {first!r}") from first
     stats = svc.stats.snapshot()
     print(f"served {len(tickets)} {args.predicate} match queries in "
           f"{dt:.2f}s ({len(tickets)/dt:.1f} qps)")
@@ -263,7 +271,7 @@ def run_stream(args) -> None:
         _export_trace(obs, args.trace)
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--workload", choices=("lm", "match", "stream"),
                     default="lm")
@@ -323,7 +331,14 @@ def main() -> None:
     ap.add_argument("--jax-profiler", action="store_true",
                     help="annotate spans into the jax profiler timeline "
                          "(jax.profiler.TraceAnnotation) as well")
-    args = ap.parse_args()
+    return ap
+
+
+def main() -> None:
+    from repro.launch.cache import enable_compile_cache
+
+    args = build_parser().parse_args()
+    enable_compile_cache()
 
     if args.workload == "match":
         run_match_service(args)

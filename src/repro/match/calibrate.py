@@ -54,6 +54,7 @@ import numpy as np
 
 from repro.core.tech import (TPU_V5E, CalibratedCostSource, CostSource,
                              KernelCurve, TPURoofline)
+from repro.kernels import default_interpret
 from repro.kernels import filter_qgram as _fq
 from repro.kernels import match_mxu as _mxu
 from repro.kernels import match_swar as _swar
@@ -159,10 +160,6 @@ def device_kind() -> str:
 
 def backend_name() -> str:
     return jax.default_backend()
-
-
-def default_interpret() -> bool:
-    return backend_name() != "tpu"
 
 
 def _slug(s: str) -> str:
@@ -572,6 +569,8 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                     help="run the autotune twice and require identical "
                          "(or cost-neutral) golden-matrix decisions")
     args = ap.parse_args(argv)
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
 
     table = autotune(fast=args.fast, verbose=True)
     for kernel in sorted(table.curves):

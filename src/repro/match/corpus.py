@@ -88,12 +88,20 @@ from . import merge as _merge
 ROW_TILE = _swar.ROW_TILE
 
 
-def _one_hot_flat(fragments: np.ndarray) -> np.ndarray:
-    """(R, F) uint8 codes -> (R, F*4) float32 char-major one-hot."""
+def _one_hot_flat(fragments: np.ndarray, width: Optional[int] = None,
+                  dtype=np.float32) -> np.ndarray:
+    """(R, F) uint8 codes -> (R, width >= F*4) char-major one-hot.
+
+    Columns past ``F*4`` are zero.  Built in row blocks so a genome-scale
+    corpus never holds an index temporary the size of the whole matrix.
+    """
     R, F = fragments.shape
-    f1h = np.zeros((R, F, 4), np.float32)
-    f1h[np.arange(R)[:, None], np.arange(F)[None, :], fragments] = 1.0
-    return f1h.reshape(R, F * 4)
+    out = np.zeros((R, F * 4 if width is None else width), dtype)
+    cols = np.arange(F, dtype=np.int32) * 4
+    for r0 in range(0, R, 1 << 16):
+        blk = fragments[r0:r0 + (1 << 16)]
+        np.put_along_axis(out[r0:r0 + blk.shape[0]], cols + blk, 1, axis=1)
+    return out
 
 
 class PackedCorpus:
@@ -430,23 +438,14 @@ class PackedCorpus:
                 if self._multiprocess:
                     self._onehot = self._build_onehot_per_host(f_chars)
                 else:
-                    base = _one_hot_flat(self._frags)
-                    base[self._n_rows:] = 0.0   # reserved rows: all-zero
-                    c_pad = self.capacity_padded
-                    if c_pad > base.shape[0]:
-                        base = np.concatenate(
-                            [base,
-                             np.zeros((c_pad - base.shape[0], base.shape[1]),
-                                      np.float32)], 0)
+                    # Reserved and padding rows stay all-zero one-hot.
                     need = max(f_chars, self.fragment_chars) * 4
-                    if base.shape[1] < need:
-                        base = np.concatenate(
-                            [base, np.zeros((base.shape[0],
-                                             need - base.shape[1]),
-                                            np.float32)], 1)
+                    base = np.zeros((self.capacity_padded, need),
+                                    jnp.bfloat16)
+                    base[:self._n_rows] = _one_hot_flat(
+                        self.fragments, need, jnp.bfloat16)
                     base = _sharding.cyclic_permute(base, self.n_shards)
-                    self._onehot = self._place(
-                        jnp.asarray(base, jnp.bfloat16))
+                    self._onehot = self._place(base)
             self.onehot_pack_count += 1
             self.obs.metrics.counter("corpus.packs").inc()
         elif self._onehot.shape[1] < f_chars * 4:

@@ -59,6 +59,7 @@ from repro.core import encoding
 from repro.core.tech import CostSource
 from repro.distributed import sharding as _sharding
 from repro.obs import Observability
+from repro.kernels import default_interpret
 from repro.kernels import filter_qgram as _fq
 from repro.kernels import match_mxu as _mxu
 from repro.kernels import match_swar as _swar
@@ -72,10 +73,6 @@ from .merge import ShardMerger
 from .index import CorpusIndex, FilterOperands, build_query_filter
 from .planner import FilterContext, Plan, Planner, kernel_name
 from .query import _UNSET, MatchQuery, as_query
-
-
-def default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @dataclasses.dataclass
@@ -465,8 +462,11 @@ class CompiledMatch:
                     res.survivor_frac = 0.0
                     return res
                 R = len(sel)
-                R_pad = -(-R // engine.corpus.row_pad) * \
-                    engine.corpus.row_pad
+                # Survivor counts differ per query and per generation;
+                # padding them to a power of two keeps the verify
+                # stage's compiled shapes to one per octave.
+                row_pad = engine.corpus.row_pad
+                R_pad = -(-(1 << (R - 1).bit_length()) // row_pad) * row_pad
                 pad_idx = np.zeros(R_pad, np.int64)
                 pad_idx[:R] = sel
                 idx_log = pad_idx
@@ -487,6 +487,12 @@ class CompiledMatch:
         # indices are physical, their order is not -- and the ref backend
         # reads the logical host buffer directly.
         shard_phys = S > 1 and idx is None and plan.backend != "ref"
+        # A resident scan's last chunk runs on into reserved capacity (the
+        # device forms cover it, as zero rows) up to a whole step, so the
+        # chunk shapes -- and the programs compiled for them -- depend on
+        # the step and the capacity, not on the live row count: appends,
+        # tombstones and compaction within capacity compile nothing new.
+        end = R_pad if idx is not None else engine.corpus.capacity_padded
 
         best_l: List[np.ndarray] = []
         best_s: List[np.ndarray] = []
@@ -508,7 +514,7 @@ class CompiledMatch:
 
         t_scan0 = time.perf_counter()
         for c0 in range(0, R_pad, step):
-            c1 = min(c0 + step, R_pad)
+            c1 = min(c0 + step, end)
             valid = min(c1, R) - c0       # rows in this chunk that are real
             if valid <= 0:
                 break                     # pure-padding tail chunk
@@ -753,9 +759,10 @@ class MatchEngine:
         self.merger = ShardMerger(
             self.mesh if self._row_shards > 1 else None,
             self._row_axes, self._row_shards, obs=self.obs)
-        # Jitted multi-controller launch cache (keyed by kernel + shape
-        # geometry): a fresh jit per chunk would retrace every call.
-        self._mp_cache: dict = {}
+        # Jitted sharded launches (keyed by kernel + static parameters;
+        # jit itself keys the shapes): a fresh shard_map per chunk would
+        # retrace and recompile every call.
+        self._launch_cache: dict = {}
         if planner is None:
             planner = Planner(cost_source=cost_source)
         elif cost_source is not None:
@@ -1003,38 +1010,16 @@ class MatchEngine:
             cm._filter_dev = (np.asarray(ops.qsig_words)
                               if merger.multiprocess
                               else jnp.asarray(ops.qsig_words))
-        sigs = self.index.signatures()
-        tile = _fq.FILTER_ROW_TILE
-        S = self._row_shards
-        if S == 1:
-            r_pad = -(-n_rows // tile) * tile
-            rows = sigs[:r_pad]
-            flags = None
-            for qi in range(ops.qsig_words.shape[0]):
-                f = _fq.filter_qgram(rows, cm._filter_dev[qi:qi + 1],
-                                     slack=ops.slacks[qi],
-                                     interpret=self.interpret)
-                flags = f if flags is None else flags | f
-            return np.asarray(flags)[:n_rows, 0].astype(bool)
-        # Per-shard live extent: shard 0 holds ceil(n/S) live rows, pad it
-        # to the filter tile; slicing [:jn] per shard block is collective-
-        # free (same reshape trick as the match chunks).
-        jf = sigs.shape[0] // S
-        jn = min(jf, -(-(-(-n_rows // S)) // tile) * tile)
-        if merger.multiprocess:
-            rows = _merge._resident_slicer(S, jf, 0, jn, sigs.shape[1])(sigs)
-        else:
-            rows = sigs.reshape(S, jf, sigs.shape[1])[:, :jn].reshape(
-                S * jn, sigs.shape[1])
+        # The kernel tests the signature form's whole extent (reserved
+        # rows are zero and their flags are dropped), so its compiled
+        # shape follows the capacity, not the live row count.
+        rows = self.index.signatures()
         flags = None
         for qi in range(ops.qsig_words.shape[0]):
-            def call(r, q, _slack=ops.slacks[qi]):
+            def filter_launch(r, q, _slack=ops.slacks[qi]):
                 return _fq.filter_qgram(r, q, slack=_slack,
                                         interpret=self.interpret)
-            f = self._shard_wrap(
-                call, PartitionSpec(None, None),
-                cache_key=("filter", ops.slacks[qi], rows.shape,
-                           cm._filter_dev.shape))(
+            f = self._shard_wrap(filter_launch, ("filter", ops.slacks[qi]))(
                 rows, cm._filter_dev[qi:qi + 1])
             flags = f if flags is None else merger.or_(flags, f)
         return merger.survivor_union(flags, n_rows)
@@ -1049,43 +1034,43 @@ class MatchEngine:
         return self._plan_query(query, n_rows)
 
     # -- kernel dispatch (one chunk, pure device) -----------------------------
-    def _shard_wrap(self, call, pat_spec=None, cache_key=None):
+    def _shard_wrap(self, call, cache_key, row_args: int = 1,
+                    rep_args: int = 1):
+        """``call`` as one jitted ``shard_map`` launch over the row axes.
+
+        ``call`` takes ``row_args`` row-sharded operands, then
+        ``rep_args`` replicated ones.  Launches are cached by
+        ``cache_key``, which must name everything ``call`` closes over: a
+        fresh closure per chunk would trace and compile every chunk
+        again.  Jitted at any process count (multi-controller, eager
+        dispatch on global arrays is not supported; host-array operands
+        get placed per the in_specs).  Unsharded engines get ``call``.
+        """
         if self.mesh is None or self._row_axes is None:
             return call
-        from jax.experimental.shard_map import shard_map
-        if self.merger.multiprocess and cache_key is not None:
-            hit = self._mp_cache.get(cache_key)
-            if hit is not None:
-                return hit
-        spec = PartitionSpec(self._row_axes if len(self._row_axes) > 1
-                             else self._row_axes[0])
-        fn = shard_map(call, mesh=self.mesh,
-                       in_specs=(spec, spec if pat_spec is None
-                                 else pat_spec),
-                       out_specs=spec, check_rep=False)
-        if self.merger.multiprocess:
-            # Multi-controller: eager dispatch on global arrays is not
-            # generally supported -- stage the whole launch through jit
-            # (host-array operands get placed per the in_specs).
-            fn = jax.jit(fn)
-            if cache_key is not None:
-                self._mp_cache[cache_key] = fn
+        fn = self._launch_cache.get(cache_key)
+        if fn is None:
+            spec = PartitionSpec(self._row_axes if len(self._row_axes) > 1
+                                 else self._row_axes[0])
+            fn = jax.jit(jax.shard_map(
+                call, mesh=self.mesh,
+                in_specs=(spec,) * row_args + (PartitionSpec(),) * rep_args,
+                out_specs=spec, check_vma=False))
+            self._launch_cache[cache_key] = fn
         return fn
 
     def _swar_chunk(self, words: jnp.ndarray, pat_rows: jnp.ndarray,
                     mask: jnp.ndarray, plan: Plan) -> jnp.ndarray:
-        if plan.predicate == "accept":
-            def call(w, p):
-                return _swar.match_swar_masks(
-                    w, p, mask, n_locs=plan.n_locs,
-                    pattern_chars=plan.pattern_chars,
-                    interpret=self.interpret)
-        else:
-            def call(w, p):
-                return _swar.match_swar(w, p, mask, n_locs=plan.n_locs,
-                                        pattern_chars=plan.pattern_chars,
-                                        interpret=self.interpret)
-        return self._shard_wrap(call)(words, pat_rows)
+        kern = (_swar.match_swar_masks if plan.predicate == "accept"
+                else _swar.match_swar)
+
+        def swar_launch(w, p, m):
+            return kern(w, p, m, n_locs=plan.n_locs,
+                        pattern_chars=plan.pattern_chars,
+                        interpret=self.interpret)
+        key = ("swar", plan.predicate, plan.n_locs, plan.pattern_chars)
+        return self._shard_wrap(swar_launch, key, row_args=2)(
+            words, pat_rows, mask)
 
     def _swar_chunk_mp(self, words, pat_rows, mask, plan: Plan):
         """Multi-controller SWAR dispatch: one jitted shard_map launch.
@@ -1104,35 +1089,23 @@ class MatchEngine:
                 "multi-process mesh (shared-pattern queries and the "
                 "batched MXU backend are); use backend=\"mxu\" or run "
                 "the patterns as separate queries")
-        key = ("swar_mp", plan.predicate, plan.n_locs, plan.pattern_chars,
-               tuple(words.shape), tuple(np.shape(pat_rows)))
-        fn = self._mp_cache.get(key)
-        if fn is None:
-            from jax.experimental.shard_map import shard_map
-            spec = PartitionSpec(self._row_axes if len(self._row_axes) > 1
-                                 else self._row_axes[0])
-            rep = PartitionSpec(None, None)
-            kern = (_swar.match_swar_masks if plan.predicate == "accept"
-                    else _swar.match_swar)
+        kern = (_swar.match_swar_masks if plan.predicate == "accept"
+                else _swar.match_swar)
 
-            def call(w, p, m):
-                pr = jnp.broadcast_to(p[0][None, :],
-                                      (w.shape[0], p.shape[1]))
-                return kern(w, pr, m, n_locs=plan.n_locs,
-                            pattern_chars=plan.pattern_chars,
-                            interpret=self.interpret)
-
-            fn = jax.jit(shard_map(call, mesh=self.mesh,
-                                   in_specs=(spec, rep, rep),
-                                   out_specs=spec, check_rep=False))
-            self._mp_cache[key] = fn
-        return fn(words, np.asarray(pat_rows), np.asarray(mask))
+        def swar_launch(w, p, m):
+            pr = jnp.broadcast_to(p[0][None, :], (w.shape[0], p.shape[1]))
+            return kern(w, pr, m, n_locs=plan.n_locs,
+                        pattern_chars=plan.pattern_chars,
+                        interpret=self.interpret)
+        key = ("swar_mp", plan.predicate, plan.n_locs, plan.pattern_chars)
+        return self._shard_wrap(swar_launch, key, rep_args=2)(
+            words, np.asarray(pat_rows), np.asarray(mask))
 
     def _mxu_chunk(self, ref_flat: jnp.ndarray, pat_mat: jnp.ndarray,
                    plan: Plan) -> jnp.ndarray:
         mp = self.merger.multiprocess
 
-        def call(r, p):
+        def mxu_launch(r, p):
             out = _mxu.match_mxu(r, p, l_pad=plan.l_pad,
                                  interpret=self.interpret)
             if mp:
@@ -1144,11 +1117,8 @@ class MatchEngine:
                 if plan.mode != "batched":
                     out = out[:, :, 0]
             return out
-        return self._shard_wrap(
-            call, PartitionSpec(None, None),
-            cache_key=("mxu", plan.l_pad, plan.n_locs, plan.n_patterns,
-                       plan.mode, tuple(ref_flat.shape),
-                       tuple(np.shape(pat_mat))))(ref_flat, pat_mat)
+        key = ("mxu", plan.l_pad, plan.n_locs, plan.n_patterns, plan.mode)
+        return self._shard_wrap(mxu_launch, key)(ref_flat, pat_mat)
 
     def _slice_resident(self, base: jnp.ndarray, c0: int,
                         c1: int) -> jnp.ndarray:

@@ -76,11 +76,14 @@ def qgram_values(codes: np.ndarray, q: int) -> np.ndarray:
     n = codes.shape[-1]
     if n < q:
         return np.zeros(codes.shape[:-1] + (0,), np.uint32)
-    vals = np.zeros(codes.shape[:-1] + (n - q + 1,), np.uint32)
-    for j in range(q):
-        vals |= codes[..., j:n - q + 1 + j].astype(np.uint32) << \
-            np.uint32(2 * j)
-    return vals
+    # Shift and OR in the narrowest type that holds 2q bits, widening
+    # once at the end: a corpus-sized index build is memory-bound here.
+    dt = np.uint8 if q <= 4 else np.uint16 if q <= 8 else np.uint32
+    c = codes.astype(dt, copy=False)
+    vals = c[..., :n - q + 1].copy()
+    for j in range(1, q):
+        vals |= c[..., j:n - q + 1 + j] << dt(2 * j)
+    return vals.astype(np.uint32, copy=False)
 
 
 def hash_bits(vals: np.ndarray, n_bits: int) -> np.ndarray:
@@ -106,23 +109,22 @@ def pack_bit_rows(bit_idx_rows: Sequence[np.ndarray], n_bits: int
     if n == 0:
         return np.zeros((0, wb), np.uint32), np.zeros(0, np.int32)
     if isinstance(bit_idx_rows, np.ndarray) and bit_idx_rows.ndim == 2:
-        row_ids = np.repeat(np.arange(n), bit_idx_rows.shape[1])
-        flat_bits = bit_idx_rows.reshape(-1)
+        row_ids = np.arange(n)[:, None]          # broadcast, no repeat
+        flat_bits = bit_idx_rows
     else:
         lens = np.fromiter((len(b) for b in bit_idx_rows), np.int64, n)
         row_ids = np.repeat(np.arange(n), lens)
         flat_bits = (np.concatenate([np.asarray(b, np.int64)
                                      for b in bit_idx_rows])
                      if lens.sum() else np.zeros(0, np.int64))
-    # Boolean occupancy matrix + lane-shift pack (the pack_codes_u32
-    # idiom): one fancy assignment and one vectorized reduction, no
-    # unbuffered ufunc.at scatter.  Duplicate bits are free.
-    occupancy = np.zeros((n, n_bits), np.uint32)
-    occupancy[row_ids, flat_bits] = 1
-    lanes = occupancy.reshape(n, wb, 32)
-    shifts = np.arange(32, dtype=np.uint32)
-    words = (lanes << shifts).sum(-1, dtype=np.uint64).astype(np.uint32)
-    counts = occupancy.sum(1).astype(np.int32)
+    # Boolean occupancy matrix packed little-endian 32 bits to a word:
+    # one fancy assignment and one packbits, no unbuffered ufunc.at
+    # scatter.  Duplicate bits are free.
+    occupancy = np.zeros((n, n_bits), bool)
+    occupancy[row_ids, flat_bits] = True
+    words = np.packbits(occupancy, axis=1, bitorder="little").view(
+        "<u4").astype(np.uint32)
+    counts = np.count_nonzero(occupancy, axis=1).astype(np.int32)
     return words, counts
 
 

@@ -154,7 +154,6 @@ class ShardMerger:
     def _replicator(self, unpermute: bool):
         fn = self._rep_fns.get(unpermute)
         if fn is None:
-            from jax.experimental.shard_map import shard_map
             S, axes = self.n_shards, self.axes
             def body(x):
                 g = jax.lax.all_gather(x, axes, axis=0, tiled=True)
@@ -164,9 +163,9 @@ class ShardMerger:
                     g = g.reshape(S, R // S, *g.shape[1:]).swapaxes(
                         0, 1).reshape(R, *g.shape[1:])
                 return g
-            fn = jax.jit(shard_map(
+            fn = jax.jit(jax.shard_map(
                 body, mesh=self.mesh, in_specs=(self._spec,),
-                out_specs=PartitionSpec(), check_rep=False))
+                out_specs=PartitionSpec(), check_vma=False))
             self._rep_fns[unpermute] = fn
         return fn
 
@@ -283,7 +282,6 @@ class ShardMerger:
 
     def _phys_topk(self):
         def build():
-            from jax.experimental.shard_map import shard_map
             S = self.n_shards
 
             def body(bs, alive_rep, c0, st_s, st_r):
@@ -321,10 +319,10 @@ class ShardMerger:
                 return out_s, out_r
 
             P0 = PartitionSpec()
-            return jax.jit(shard_map(
+            return jax.jit(jax.shard_map(
                 body, mesh=self.mesh,
                 in_specs=(self._spec, P0, P0, P0, P0),
-                out_specs=(P0, P0), check_rep=False))
+                out_specs=(P0, P0), check_vma=False))
         return self._jit("phys_topk", build)
 
     def _logical_topk(self):

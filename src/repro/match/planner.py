@@ -359,6 +359,11 @@ class Planner:
             rows = int(self.memory_budget_bytes * n_shards
                        // max(plan_bytes_per_row, 1))
             chunk = max(row_tile, (rows // row_tile) * row_tile)
+            # Large chunks stay lane-tile aligned per shard, so the SWAR
+            # kernel never pads a chunk (a copy) to its 128-row grid.
+            lane = _swar.LANE_TILE * n_shards
+            if chunk >= lane:
+                chunk = chunk // lane * lane
         return min(chunk, R_pad)
 
     # -- the planner ----------------------------------------------------------

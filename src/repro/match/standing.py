@@ -54,11 +54,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import encoding
+from repro.kernels import default_interpret
 from repro.kernels import filter_qgram as _fq
 from repro.kernels import match_swar as _swar
 from repro.match import index as _idx
-from repro.match.engine import _pack_mask_planes, _valid_mask, \
-    default_interpret
+from repro.match.engine import _pack_mask_planes, _valid_mask
 from repro.match.feedback import EwmaRatio
 from repro.match.planner import BankPlan, Planner, _swar_geometry
 from repro.match.query import MatchQuery, as_masks

@@ -218,3 +218,31 @@ class TestPricingAndStats:
             svc.submit(np.zeros(P, np.uint8), reduction="nope")
         with pytest.raises(ValueError, match="requires a threshold"):
             svc.submit(np.zeros(P, np.uint8), reduction="threshold")
+
+
+class TestMatchLauncher:
+    """``repro.launch.serve --workload match`` fails loudly: the service
+    isolates a failing group on its tickets, the launcher does not."""
+
+    ARGV = ["--workload", "match", "--corpus-rows", "32",
+            "--fragment-chars", "64", "--pattern-chars", "16",
+            "--requests", "8", "--ingest-every", "4", "--tick-every", "4"]
+
+    def _args(self):
+        from repro.launch import serve
+        return serve, serve.build_parser().parse_args(self.ARGV)
+
+    def test_clean_run_passes(self, capsys):
+        serve, args = self._args()
+        serve.run_match_service(args)
+        assert "served 8 exact match queries" in capsys.readouterr().out
+
+    def test_failing_group_fails_launcher(self, monkeypatch):
+        serve, args = self._args()
+
+        def refuse(self, grp):
+            raise RuntimeError("kernel refused by the compiler")
+        monkeypatch.setattr(MatchService, "_run_group", refuse)
+        with pytest.raises(RuntimeError, match="match queries failed") as ei:
+            serve.run_match_service(args)
+        assert "kernel refused" in str(ei.value.__cause__)

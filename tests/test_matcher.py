@@ -31,6 +31,32 @@ class TestEncoding:
         np.testing.assert_array_equal(
             encoding.unpack_codes_u32(words, 37), codes)
 
+    @pytest.mark.parametrize("bits,n", [(2, 1), (2, 16), (2, 37),
+                                        (2, 256), (1, 33)])
+    def test_pack_u32_matches_definition(self, bits, n):
+        """Char i sits at bits [i*bits, (i+1)*bits) of word i // (32/bits)
+        (the strided-pass packer against the per-character definition)."""
+        rng = np.random.default_rng(n)
+        codes = rng.integers(0, 1 << bits, (4, n), np.uint8)
+        cpw = 32 // bits
+        want = np.zeros((4, -(-n // cpw)), np.uint64)
+        for r in range(4):
+            for i in range(n):
+                want[r, i // cpw] |= int(codes[r, i]) << ((i % cpw) * bits)
+        np.testing.assert_array_equal(encoding.pack_codes_u32(codes, bits),
+                                      want.astype(np.uint32))
+
+    def test_one_hot_form_matches_definition(self):
+        """Column f*4 + c is 1 iff fragment char f is code c; pad is 0."""
+        from repro.match.corpus import _one_hot_flat
+        rng = np.random.default_rng(3)
+        frags = rng.integers(0, 4, (70_000, 9), np.uint8)  # > one block
+        got = _one_hot_flat(frags, 40)
+        want = np.zeros((70_000, 40), np.float32)
+        for f in range(9):
+            want[np.arange(70_000), f * 4 + frags[:, f]] = 1
+        np.testing.assert_array_equal(got, want)
+
     def test_fold_reference_overlap(self):
         """Adjacent fragments overlap by P-1 so no alignment is lost."""
         rng = np.random.default_rng(2)

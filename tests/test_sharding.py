@@ -8,13 +8,14 @@ import jax
 from jax.sharding import Mesh, PartitionSpec
 
 from repro.distributed import hlo_analysis, sharding
+from repro.launch.mesh import make_debug_mesh
 
 
 def tiny_mesh():
     devs = jax.devices()
     if len(devs) < 4:
         pytest.skip("needs >=4 devices (run under forced host device count)")
-    return jax.make_mesh((2, 2), ("data", "model"), devices=devs[:4])
+    return make_debug_mesh(2, 2)
 
 
 class TestRules:
