@@ -1,0 +1,24 @@
+"""Share of the traced window in which the device sat idle while the host
+did the engine's own work: planning, packing, the filter's survivor
+union, per-chunk bookkeeping and the assembly of each answer, in percent.
+
+Each idle gap of the cell's first chip is named by the innermost program
+span over it (``bench/tracing.py``); this counts the gaps named in
+``SPANS`` (``match.run`` itself is what no child span covers).  One of
+the five ``idle_*_share`` readers that split ``device_idle_share``.
+Moves queries_per_s.
+"""
+
+SPANS = frozenset({"match.run", "plan", "pack", "filter", "filter.union",
+                   "chunk.host", "result", "compact", "bank.scan"})
+
+
+def counts(name: str) -> bool:
+    return name in SPANS
+
+
+def read(ctx):
+    red = ctx["trace"]
+    if red is None or red.n_devices == 0 or red.window_s <= 0:
+        return None
+    return 100.0 * sum(s for n, s in red.gaps if counts(n)) / red.window_s

@@ -18,7 +18,6 @@ Modules (one per paper table/figure + assignment deliverables):
   standing_bench    -- fused standing-query bank vs per-pattern loop
   shard_bench       -- mesh-sharded 1M-row scaling sweep (beyond paper)
   calibrate_bench   -- autotuned cost model: the three Sec. 3i proofs
-  obs_bench         -- tracing/metrics overhead gate + trace validation
   roofline          -- dry-run roofline table (assignment)
 
 Modules that maintain a committed ``BENCH_*.json`` artifact also print one
@@ -46,7 +45,7 @@ MODULES = [
     "fig8_tech", "fig9_10_nmp", "fig11_gates", "table4_apps",
     "sec5_5_variation", "kernel_bench", "service_bench", "query_bench",
     "ingest_bench", "filter_bench", "standing_bench", "shard_bench",
-    "calibrate_bench", "obs_bench",
+    "calibrate_bench",
     "roofline",
 ]
 
@@ -71,10 +70,7 @@ def main() -> None:
             if summary is not None:
                 line = summary()
                 if line:
-                    # A module may brand its artifact line (obs_bench
-                    # prints as ``obs,artifact,...``).
-                    label = getattr(mod, "SUMMARY_NAME", name)
-                    print(f"{label},artifact,{line}")
+                    print(f"{name},artifact,{line}")
         except Exception:
             failures += 1
             print(f"{name},ERROR,{traceback.format_exc(limit=1).splitlines()[-1]}")

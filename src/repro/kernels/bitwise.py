@@ -57,4 +57,5 @@ def bitwise(op: str, a: jnp.ndarray, b: jnp.ndarray | None = None,
         out_specs=pl.BlockSpec((N_TILE, W), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((N, W), jnp.uint32),
         interpret=interpret,
+        name="bitwise",
     )(a, b)

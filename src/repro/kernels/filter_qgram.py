@@ -90,6 +90,7 @@ def filter_qgram(row_sigs: jnp.ndarray, qsig: jnp.ndarray, *, slack: int,
         out_specs=pl.BlockSpec((tile, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((R, 1), jnp.int32),
         interpret=interpret,
+        name="filter_qgram",
     )(row_sigs, qsig)
 
 
@@ -175,6 +176,7 @@ def bank_prefilter(pat_sigs: jnp.ndarray, doc_sigs: jnp.ndarray,
         out_specs=pl.BlockSpec((1, tile), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, Q), jnp.int32),
         interpret=interpret,
+        name="bank_prefilter",
     )(pat_sigs, doc_sigs, slacks.reshape(1, Q))
     return out.reshape(Q, 1)
 

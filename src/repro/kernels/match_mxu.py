@@ -106,4 +106,5 @@ def match_mxu(ref_flat: jnp.ndarray, pat_mat: jnp.ndarray, *, l_pad: int,
         out_specs=pl.BlockSpec((1, l_pad, Q), lambda r: (r, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((R, l_pad, Q), jnp.float32),
         interpret=interpret,
+        name="match_mxu",
     )(planes, pat_t)

@@ -162,7 +162,7 @@ def _swar_masks_kernel(ref_ref, plane_ref, mask_ref, out_ref, words_scr,
 
 
 def _launch(kernel, ref_words, pat_cols, valid_mask, *, n_locs: int,
-            wp: int, interpret: bool):
+            wp: int, interpret: bool, masks: bool):
     """Shared pallas_call for both SWAR kernels (lane-tiled row grid)."""
     R, W = ref_words.shape
     if R % ROW_TILE:
@@ -193,6 +193,7 @@ def _launch(kernel, ref_words, pat_cols, valid_mask, *, n_locs: int,
         scratch_shapes=[pltpu.VMEM((word_rows, tile), jnp.uint32),
                         pltpu.VMEM((l_pad, tile), jnp.int32)],
         interpret=interpret,
+        name="match_swar_masks" if masks else "match_swar",
     )(ref_words, pat_cols, valid_mask)
     return out if r_pad == R else out[:R]
 
@@ -207,7 +208,7 @@ def match_swar(ref_words: jnp.ndarray, pat_words: jnp.ndarray,
     kernel = functools.partial(_swar_kernel, n_locs=n_locs,
                                pattern_chars=pattern_chars, wp=wp)
     return _launch(kernel, ref_words, pat_words, valid_mask, n_locs=n_locs,
-                   wp=wp, interpret=interpret)
+                   wp=wp, interpret=interpret, masks=False)
 
 
 @functools.partial(jax.jit, static_argnames=("n_locs", "pattern_chars",
@@ -224,4 +225,4 @@ def match_swar_masks(ref_words: jnp.ndarray, pat_planes: jnp.ndarray,
     kernel = functools.partial(_swar_masks_kernel, n_locs=n_locs,
                                pattern_chars=pattern_chars, wp=wp)
     return _launch(kernel, ref_words, pat_planes, valid_mask,
-                   n_locs=n_locs, wp=wp, interpret=interpret)
+                   n_locs=n_locs, wp=wp, interpret=interpret, masks=True)

@@ -50,4 +50,5 @@ def popcount(words: jnp.ndarray, *, interpret: bool = False) -> jnp.ndarray:
         out_specs=pl.BlockSpec((N_TILE, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((N, 1), jnp.int32),
         interpret=interpret,
+        name="popcount",
     )(words)

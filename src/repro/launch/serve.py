@@ -61,6 +61,7 @@ def _export_trace(obs: Observability, path: str) -> None:
 
 def _print_metrics(svc, tick_label: str) -> None:
     """One greppable per-interval metrics line (``--metrics-every``)."""
+    svc.publish_gauges()
     s = svc.stats
     m = svc.obs.metrics
     print(f"metrics,{tick_label},"

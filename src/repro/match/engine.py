@@ -343,9 +343,10 @@ class CompiledMatch:
 
         With the engine's tracer enabled the whole execution runs under
         a ``match.run`` span (plan / pack / filter / launch / merge /
-        pull children) and the result carries the per-stage breakdown in
-        ``timings``; disabled (the default) this wrapper is two branch
-        instructions.
+        pull children, one ``chunk.host`` per chunk and a ``result``
+        around the answer's assembly) and the result carries the
+        per-stage breakdown in ``timings``; disabled (the default) this
+        wrapper is two branch instructions.
         """
         tr = self.engine.obs.tracer
         if not tr.enabled:
@@ -429,12 +430,13 @@ class CompiledMatch:
                     t0 = time.perf_counter()
                     flags = engine._run_filter(self, R)
                     t_fil = time.perf_counter() - t0
-                    sel = np.flatnonzero(flags).astype(np.int64)
-                    if dead_full is not None:
-                        # Tombstoned rows can survive the signature test
-                        # but must not reach the verify stage (nor the
-                        # hits).
-                        sel = sel[~dead_full[sel]]
+                    with tr.span("filter.union"):
+                        sel = np.flatnonzero(flags).astype(np.int64)
+                        if dead_full is not None:
+                            # Tombstoned rows can survive the signature
+                            # test but must not reach the verify stage
+                            # (nor the hits).
+                            sel = sel[~dead_full[sel]]
                     survivor_frac = len(sel) / R
                     if tr.enabled:
                         sp_fil.set("survivor_frac", survivor_frac)
@@ -518,169 +520,180 @@ class CompiledMatch:
             valid = min(c1, R) - c0       # rows in this chunk that are real
             if valid <= 0:
                 break                     # pure-padding tail chunk
-            # The launch span measures kernel *dispatch* (JAX is async);
-            # the device wait lands in the merge layer's pull spans.
-            with tr.span("launch",
-                         {"c0": c0, "rows": valid} if tr.enabled else None):
-                scores = engine._chunk_scores(plan, self._pats2d, c0, c1,
-                                              self._packed, idx, idx_log)
-            n_chunks += 1
-            # Per-chunk tombstone mask in logical row order (None when the
-            # whole chunk is alive).
-            alive = None
-            if dead_full is not None:
-                chunk_ids = (np.arange(c0, c0 + valid, dtype=np.int64)
-                             if sel is None
-                             else np.asarray(sel[c0:c0 + valid]))
-                alive = ~dead_full[chunk_ids]
-                if alive.all():
-                    alive = None
-            if reduction == "full":
-                # Host materialization is the point of this reduction (the
-                # one case where the whole block crosses); the pull
-                # replicates + un-permutes device-side first.
-                sc = merger.pull(scores, unpermute=shard_phys,
-                                 kind="block")[:valid]
-                if alive is not None:
-                    # Dead rows report the -1 sentinel (scores are >= 0
-                    # for live rows, so the sentinel is unambiguous).
-                    sc = sc.copy()
-                    sc[~alive] = -1
-                full.append(sc)
-                continue
-            # Fused per-chunk reduction, jitted through the merge layer:
-            # only reduced per-row state ever crosses to the host, and no
-            # eager op touches a (possibly non-addressable) sharded array.
-            bl, bs = merger.chunk_best(scores)
-            bl_np = merger.pull(bl, unpermute=shard_phys)[:valid]
-            bs_np = merger.pull(bs, unpermute=shard_phys)[:valid]
-            if alive is not None:
-                bl_np, bs_np = bl_np.copy(), bs_np.copy()
-                bl_np[~alive] = 0
-                bs_np[~alive] = -1        # dead-row best-score sentinel
-            best_l.append(bl_np)
-            best_s.append(bs_np)
-            # topk / threshold report *corpus* row ids; with a rows= subset
-            # that means mapping chunk positions through the selection.
-            if reduction == "threshold":
-                # Two-phase sparse pull (the per-chunk host-transfer fix):
-                # first a per-row any-hit bitmap, then a device gather of
-                # only the hot rows' score vectors -- the full (chunk, L
-                # [, Q]) block never crosses to the host.
-                hot = merger.hot_mask(scores, thr_int)
-                hot_np = merger.pull(hot, unpermute=shard_phys)[:valid]
-                if alive is not None:
-                    hot_np = hot_np & alive
-                hot_rows = np.flatnonzero(hot_np)
-                if hot_rows.size == 0:
+            # Everything the host does for one chunk; the launch, merge
+            # and pull spans nest inside, so this span's self time is
+            # the chunk's host bookkeeping.
+            with tr.span("chunk.host"):
+                # The launch span measures kernel *dispatch* (JAX is async);
+                # the device wait lands in the merge layer's pull spans.
+                with tr.span("launch", {"c0": c0, "rows": valid}
+                             if tr.enabled else None):
+                    scores = engine._chunk_scores(plan, self._pats2d, c0,
+                                                  c1, self._packed, idx,
+                                                  idx_log)
+                n_chunks += 1
+                # Per-chunk tombstone mask in logical row order (None when the
+                # whole chunk is alive).
+                alive = None
+                if dead_full is not None:
+                    chunk_ids = (np.arange(c0, c0 + valid, dtype=np.int64)
+                                 if sel is None
+                                 else np.asarray(sel[c0:c0 + valid]))
+                    alive = ~dead_full[chunk_ids]
+                    if alive.all():
+                        alive = None
+                if reduction == "full":
+                    # Host materialization is the point of this reduction (the
+                    # one case where the whole block crosses); the pull
+                    # replicates + un-permutes device-side first.
+                    sc = merger.pull(scores, unpermute=shard_phys,
+                                     kind="block")[:valid]
+                    if alive is not None:
+                        # Dead rows report the -1 sentinel (scores are >= 0
+                        # for live rows, so the sentinel is unambiguous).
+                        sc = sc.copy()
+                        sc[~alive] = -1
+                    full.append(sc)
                     continue
-                if shard_phys:
-                    # Physical positions of the hot logical rows inside
-                    # this chunk's shard-major layout.
-                    jc = int(scores.shape[0]) // S
-                    pos = (hot_rows % S) * jc + hot_rows // S
-                else:
-                    pos = hot_rows
-                # Pad the gather to a power of two so hot-count jitter
-                # doesn't recompile the gather every chunk.
-                n_hot = pos.size
-                pad_n = max(8, 1 << (int(n_hot) - 1).bit_length())
-                pos_pad = np.zeros(pad_n, np.int64)
-                pos_pad[:n_hot] = pos
-                sc = merger.pull(merger.gather_rows(scores, pos_pad),
-                                 kind="block")[:n_hot]
-                if plan.mode == "batched":
-                    local = np.argwhere(sc >= thr_vec[None, None, :])
-                else:
-                    local = np.argwhere(sc >= float(thr_vec[0]))
-                if local.size:
-                    vals = sc[tuple(local.T)]
-                    # Hot rows are ascending, so argwhere order over the
-                    # gathered block equals the full-block hit order.
-                    rows_chunk = hot_rows[local[:, 0]]
-                    local[:, 0] = (sel[rows_chunk + c0] if sel is not None
-                                   else rows_chunk + c0)
-                    hit_rows.append(np.concatenate(
-                        [local, vals[:, None].astype(np.int64)], 1))
+                # Fused per-chunk reduction, jitted through the merge layer:
+                # only reduced per-row state ever crosses to the host, and no
+                # eager op touches a (possibly non-addressable) sharded array.
+                bl, bs = merger.chunk_best(scores)
+                bl_np = merger.pull(bl, unpermute=shard_phys)[:valid]
+                bs_np = merger.pull(bs, unpermute=shard_phys)[:valid]
+                if alive is not None:
+                    bl_np, bs_np = bl_np.copy(), bs_np.copy()
+                    bl_np[~alive] = 0
+                    bs_np[~alive] = -1        # dead-row best-score sentinel
+                best_l.append(bl_np)
+                best_s.append(bs_np)
+                # topk / threshold report *corpus* row ids; with a rows= subset
+                # that means mapping chunk positions through the selection.
+                if reduction == "threshold":
+                    # Two-phase sparse pull (the per-chunk host-transfer fix):
+                    # first a per-row any-hit bitmap, then a device gather of
+                    # only the hot rows' score vectors -- the full (chunk, L
+                    # [, Q]) block never crosses to the host.
+                    hot = merger.hot_mask(scores, thr_int)
+                    hot_np = merger.pull(hot, unpermute=shard_phys)[:valid]
+                    if alive is not None:
+                        hot_np = hot_np & alive
+                    hot_rows = np.flatnonzero(hot_np)
+                    if hot_rows.size == 0:
+                        continue
+                    if shard_phys:
+                        # Physical positions of the hot logical rows inside
+                        # this chunk's shard-major layout.
+                        jc = int(scores.shape[0]) // S
+                        pos = (hot_rows % S) * jc + hot_rows // S
+                    else:
+                        pos = hot_rows
+                    # Pad the gather to a power of two so hot-count jitter
+                    # doesn't recompile the gather every chunk.
+                    n_hot = pos.size
+                    pad_n = max(8, 1 << (int(n_hot) - 1).bit_length())
+                    pos_pad = np.zeros(pad_n, np.int64)
+                    pos_pad[:n_hot] = pos
+                    sc = merger.pull(merger.gather_rows(scores, pos_pad),
+                                     kind="block")[:n_hot]
+                    if plan.mode == "batched":
+                        local = np.argwhere(sc >= thr_vec[None, None, :])
+                    else:
+                        local = np.argwhere(sc >= float(thr_vec[0]))
+                    if local.size:
+                        vals = sc[tuple(local.T)]
+                        # Hot rows are ascending, so argwhere order over the
+                        # gathered block equals the full-block hit order.
+                        rows_chunk = hot_rows[local[:, 0]]
+                        local[:, 0] = (sel[rows_chunk + c0] if sel is not None
+                                       else rows_chunk + c0)
+                        hit_rows.append(np.concatenate(
+                            [local, vals[:, None].astype(np.int64)], 1))
+                elif reduction == "topk":
+                    # Device-side tree merge (ShardMerger): shard-local maxima
+                    # + all_gather + replicated lexsort, or -- on logical-order
+                    # paths -- a jitted sentinel merge.  Dead/padding rows ride
+                    # the (-1, ROW_SENTINEL) sentinel pair and sort last.
+                    if topk_state is None:
+                        topk_state = merger.topk_init(
+                            self._k_eff,
+                            plan.n_patterns if plan.mode == "batched" else 0)
+                    n_bs = int(bs.shape[0])
+                    alive_chunk = np.zeros(n_bs, bool)
+                    alive_chunk[:valid] = True if alive is None else alive
+                    n_topk_alive += (valid if alive is None
+                                     else int(alive.sum()))
+                    if shard_phys:
+                        topk_state = merger.topk_update(
+                            topk_state, bs, phys=True,
+                            alive_chunk=alive_chunk, c0=c0)
+                    else:
+                        rows_full = np.zeros(n_bs, np.int64)
+                        rows_full[:valid] = (np.arange(c0, c0 + valid)
+                                             if sel is None
+                                             else sel[c0:c0 + valid])
+                        topk_state = merger.topk_update(
+                            topk_state, bs, phys=False,
+                            alive_chunk=alive_chunk, rows_np=rows_full)
+
+        # Assembling the answer: concatenations, the top-k finalize and
+        # hits, plan-vs-actual and feedback records.
+        with tr.span("result"):
+            if n_chunks:
+                # Observed scan/verify-stage wall time vs. the feedback-free
+                # estimate at the *actual* rows scanned (for a filtered run the
+                # plan priced estimated survivors; recomputing at the measured
+                # count keeps selectivity error out of the kernel-cost EWMA --
+                # selectivity has its own feedback in CorpusIndex).  The ref
+                # backend is priced at total rows, kernels per shard.  The
+                # plan-vs-actual registry always gets the record; the feedback
+                # store (which mutates future plans) only when enabled.
+                r_price = (R if plan.backend == "ref"
+                           else -(-R // plan.n_shards))
+                base = engine.planner.backend_seconds(
+                    plan.backend, r_price, plan.n_locs, plan.pattern_chars,
+                    plan.n_patterns, plan.predicate, base=True)
+                s_key = kernel_key(kernel_name(plan.backend,
+                                               plan.predicate), r_price,
+                                   plan.pattern_chars, plan.n_patterns)
+                t_scan = time.perf_counter() - t_scan0
+                engine.obs.record_plan_actual(s_key, base, t_scan)
+                if engine.record_runtimes:
+                    engine.planner.feedback.observe(s_key, base, t_scan)
+
+            if reduction == "full":
+                all_scores = np.concatenate(full, 0)
+                return MatchResult(plan=plan, best_locs=all_scores.argmax(1),
+                                   best_scores=all_scores.max(1),
+                                   scores=all_scores, n_chunks=n_chunks,
+                                   n_shards=S, merge_path=merger.merge_path,
+                                   collective_bytes=merger.collective_bytes
+                                   - coll0)
+            best_locs = np.concatenate(best_l, 0)
+            best_scores = np.concatenate(best_s, 0)
+            res = MatchResult(plan=plan, best_locs=best_locs,
+                              best_scores=best_scores, n_chunks=n_chunks,
+                              n_shards=S, merge_path=merger.merge_path)
+            if survivor_frac is not None:
+                res.survivor_rows = sel
+                res.survivor_frac = survivor_frac
+            if reduction == "threshold":
+                width = 3 + (1 if plan.mode == "batched" else 0)
+                res.hits = (np.concatenate(hit_rows, 0) if hit_rows
+                            else np.zeros((0, width), np.int64))
             elif reduction == "topk":
-                # Device-side tree merge (ShardMerger): shard-local maxima
-                # + all_gather + replicated lexsort, or -- on logical-order
-                # paths -- a jitted sentinel merge.  Dead/padding rows ride
-                # the (-1, ROW_SENTINEL) sentinel pair and sort last.
-                if topk_state is None:
-                    topk_state = merger.topk_init(
-                        self._k_eff,
-                        plan.n_patterns if plan.mode == "batched" else 0)
-                n_bs = int(bs.shape[0])
-                alive_chunk = np.zeros(n_bs, bool)
-                alive_chunk[:valid] = True if alive is None else alive
-                n_topk_alive += valid if alive is None else int(alive.sum())
-                if shard_phys:
-                    topk_state = merger.topk_update(
-                        topk_state, bs, phys=True,
-                        alive_chunk=alive_chunk, c0=c0)
+                if topk_state is None or n_topk_alive == 0:
+                    # Every scanned row was tombstoned: a well-formed empty
+                    # top-k (matches the empty-subset result shape).
+                    shape0 = ((0, plan.n_patterns) if plan.mode == "batched"
+                              else (0,))
+                    res.topk_rows = np.zeros(shape0, np.int64)
+                    res.topk_scores = np.zeros(shape0, np.int32)
                 else:
-                    rows_full = np.zeros(n_bs, np.int64)
-                    rows_full[:valid] = (np.arange(c0, c0 + valid)
-                                         if sel is None
-                                         else sel[c0:c0 + valid])
-                    topk_state = merger.topk_update(
-                        topk_state, bs, phys=False,
-                        alive_chunk=alive_chunk, rows_np=rows_full)
-
-        if n_chunks:
-            # Observed scan/verify-stage wall time vs. the feedback-free
-            # estimate at the *actual* rows scanned (for a filtered run the
-            # plan priced estimated survivors; recomputing at the measured
-            # count keeps selectivity error out of the kernel-cost EWMA --
-            # selectivity has its own feedback in CorpusIndex).  The ref
-            # backend is priced at total rows, kernels per shard.  The
-            # plan-vs-actual registry always gets the record; the feedback
-            # store (which mutates future plans) only when enabled.
-            r_price = R if plan.backend == "ref" else -(-R // plan.n_shards)
-            base = engine.planner.backend_seconds(
-                plan.backend, r_price, plan.n_locs, plan.pattern_chars,
-                plan.n_patterns, plan.predicate, base=True)
-            s_key = kernel_key(kernel_name(plan.backend, plan.predicate),
-                               r_price, plan.pattern_chars, plan.n_patterns)
-            t_scan = time.perf_counter() - t_scan0
-            engine.obs.record_plan_actual(s_key, base, t_scan)
-            if engine.record_runtimes:
-                engine.planner.feedback.observe(s_key, base, t_scan)
-
-        if reduction == "full":
-            all_scores = np.concatenate(full, 0)
-            return MatchResult(plan=plan, best_locs=all_scores.argmax(1),
-                               best_scores=all_scores.max(1),
-                               scores=all_scores, n_chunks=n_chunks,
-                               n_shards=S, merge_path=merger.merge_path,
-                               collective_bytes=merger.collective_bytes
-                               - coll0)
-        best_locs = np.concatenate(best_l, 0)
-        best_scores = np.concatenate(best_s, 0)
-        res = MatchResult(plan=plan, best_locs=best_locs,
-                          best_scores=best_scores, n_chunks=n_chunks,
-                          n_shards=S, merge_path=merger.merge_path)
-        if survivor_frac is not None:
-            res.survivor_rows = sel
-            res.survivor_frac = survivor_frac
-        if reduction == "threshold":
-            width = 3 + (1 if plan.mode == "batched" else 0)
-            res.hits = (np.concatenate(hit_rows, 0) if hit_rows
-                        else np.zeros((0, width), np.int64))
-        elif reduction == "topk":
-            if topk_state is None or n_topk_alive == 0:
-                # Every scanned row was tombstoned: a well-formed empty
-                # top-k (matches the empty-subset result shape).
-                shape0 = ((0, plan.n_patterns) if plan.mode == "batched"
-                          else (0,))
-                res.topk_rows = np.zeros(shape0, np.int64)
-                res.topk_scores = np.zeros(shape0, np.int32)
-            else:
-                res.topk_rows, res.topk_scores = merger.topk_finalize(
-                    topk_state, n_topk_alive, self._k_eff)
-        res.collective_bytes = merger.collective_bytes - coll0
-        return res
+                    res.topk_rows, res.topk_scores = merger.topk_finalize(
+                        topk_state, n_topk_alive, self._k_eff)
+            res.collective_bytes = merger.collective_bytes - coll0
+            return res
 
     __call__ = run
 
@@ -1014,15 +1027,23 @@ class MatchEngine:
         # rows are zero and their flags are dropped), so its compiled
         # shape follows the capacity, not the live row count.
         rows = self.index.signatures()
+        tr = self.obs.tracer
         flags = None
         for qi in range(ops.qsig_words.shape[0]):
             def filter_launch(r, q, _slack=ops.slacks[qi]):
                 return _fq.filter_qgram(r, q, slack=_slack,
                                         interpret=self.interpret)
-            f = self._shard_wrap(filter_launch, ("filter", ops.slacks[qi]))(
-                rows, cm._filter_dev[qi:qi + 1])
-            flags = f if flags is None else merger.or_(flags, f)
-        return merger.survivor_union(flags, n_rows)
+            with tr.span("filter.launch"):
+                f = self._shard_wrap(filter_launch,
+                                     ("filter", ops.slacks[qi]))(
+                    rows, cm._filter_dev[qi:qi + 1])
+            if flags is None:
+                flags = f
+                continue
+            with tr.span("filter.union"):
+                flags = merger.or_(flags, f)
+        with tr.span("filter.union"):
+            return merger.survivor_union(flags, n_rows)
 
     def plan(self, patterns, *, backend=_UNSET, mode=_UNSET, rows=_UNSET,
              chunk_rows=_UNSET) -> Plan:

@@ -61,7 +61,22 @@ SCORE_SENTINEL = np.int32(-1)
 # shards multi-controller).  Every process packs the touched rows (tiny,
 # identical host work by SPMD discipline); XLA updates only the
 # addressable slots.
-scatter_rows = jax.jit(lambda a, i, v: a.at[i, :].set(v))
+@jax.jit
+def scatter_rows(a, i, v):
+    return a.at[i, :].set(v)
+
+
+# A jitted program is named after its function (``jit_or``, not
+# ``jit__lambda``): that name is how the device trace tells merges apart.
+def _or(x, y):
+    return x | y
+
+
+_or.__name__ = _or.__qualname__ = "or"
+
+
+def _take_rows(a, i):
+    return jnp.take(a, i, axis=0)
 
 
 @functools.lru_cache(maxsize=512)
@@ -181,16 +196,21 @@ class ShardMerger:
         tr = self.obs.tracer
         with tr.span("pull",
                      {"kind": kind} if tr.enabled else None) as sp:
-            if self._sharded(x):
-                rep = self._replicator(unpermute)(x)
+            sharded = self._sharded(x)
+            if sharded:
+                x = self._replicator(unpermute)(x)
                 self.n_collectives += 1
-                self.collective_bytes += (int(rep.nbytes)
+                self.collective_bytes += (int(x.nbytes)
                                           * (self.n_shards - 1)) \
                     // self.n_shards
-                out = np.asarray(rep)
-            else:
+            # The device still computing, then the copy to the host: an
+            # idle device under ``pull.copy`` is the transfer (or the
+            # host), under ``pull.wait`` never.
+            with tr.span("pull.wait"):
+                jax.block_until_ready(x)
+            with tr.span("pull.copy"):
                 out = np.asarray(x)
-                if unpermute and self.n_shards > 1:
+                if not sharded and unpermute and self.n_shards > 1:
                     out = _sharding.cyclic_unpermute(out, self.n_shards)
             self.n_pulls += 1
             if kind == "block":
@@ -210,8 +230,9 @@ class ShardMerger:
 
     def chunk_best(self, scores):
         """(rows, L[, Q]) -> ((rows[, Q]) argmax, (rows[, Q]) max), jitted."""
-        fn = self._jit("best", lambda: jax.jit(
-            lambda s: (jnp.argmax(s, axis=1), jnp.max(s, axis=1))))
+        def chunk_best(s):
+            return jnp.argmax(s, axis=1), jnp.max(s, axis=1)
+        fn = self._jit("best", lambda: jax.jit(chunk_best))
         tr = self.obs.tracer
         with tr.span("merge", {"op": "best"} if tr.enabled else None):
             return fn(scores)
@@ -225,10 +246,10 @@ class ShardMerger:
         hit extraction.
         """
         def build():
-            def hot(s, t):
+            def hot_mask(s, t):
                 m = (s >= t[None, None, :]) if s.ndim == 3 else (s >= t)
                 return m.any(axis=tuple(range(1, m.ndim)))
-            return jax.jit(hot)
+            return jax.jit(hot_mask)
         tr = self.obs.tracer
         with tr.span("merge", {"op": "hot_mask"} if tr.enabled else None):
             return self._jit("hot", build)(scores,
@@ -236,7 +257,7 @@ class ShardMerger:
 
     def or_(self, a, b):
         """Jitted elementwise OR (filter flag union across patterns)."""
-        return self._jit("or", lambda: jax.jit(lambda x, y: x | y))(a, b)
+        return self._jit("or", lambda: jax.jit(_or))(a, b)
 
     def gather_rows(self, arr, idx: np.ndarray):
         """Rows ``idx`` of a (possibly row-sharded) array, replicated.
@@ -254,8 +275,7 @@ class ShardMerger:
             arr = self._localize(arr)
             def build():
                 ns = NamedSharding(self.mesh, PartitionSpec())
-                return jax.jit(lambda a, i: jnp.take(a, i, axis=0),
-                               out_shardings=ns)
+                return jax.jit(_take_rows, out_shardings=ns)
             out = self._jit("gather", build)(arr, idx)
             self.n_collectives += 1
             self.collective_bytes += (int(out.nbytes)

@@ -98,8 +98,6 @@ class ServiceStats:
     # ``avg_latency_s`` remain below as thin views over it.
     latency_hist: LogHistogram = dataclasses.field(
         default_factory=LogHistogram, repr=False)
-    n_shards: int = 1                 # engine row shards (mesh-resident)
-    shard_rows: Optional[List[int]] = None   # live rows per shard
     # Cross-shard merge accounting (DESIGN.md Sec. 3k): which path the
     # engine's reductions combine on ("device" = collectives under
     # shard_map, "host" = single-shard pulls) and the cumulative
@@ -108,32 +106,78 @@ class ServiceStats:
     # visible in the same snapshot the feedback loop reads.
     merge_path: str = "host"
     collective_bytes: int = 0
-    # Cost-model provenance (DESIGN.md Sec. 3i): which source prices the
-    # planner's decisions ("static" | "calibrated:<digest8>") and the
-    # runtime-feedback state (observation/misprediction counters, number
-    # of re-priced shape buckets) -- refreshed per tick from the planner.
-    cost_source: str = "static"
-    feedback: Optional[Dict] = None
-    # Standing-query / windowed-corpus counters (DESIGN.md Sec. 3j):
-    # bank launch counts mirror the attached PatternBank per tick, so
-    # "one ingest batch = one fused bank launch" is auditable here.
-    n_bank_launches: int = 0          # fused bank verify dispatches
-    n_bank_prefilter_launches: int = 0
+    # Standing-query / windowed-corpus counters (DESIGN.md Sec. 3j).
     n_bank_hits: int = 0              # standing hits delivered via ingest
     n_evicted_rows: int = 0           # rows tombstoned by the window
-    n_compactions: int = 0            # corpus compactions triggered
-    bank: Optional[Dict] = None       # PatternBank.stats() snapshot
-    # Obs-layer views (DESIGN.md Sec. 3l), refreshed per tick: per-stage
-    # wall seconds summed over the latest tick's launches (from the
-    # ``MatchResult.timings`` span breakdowns) and the registry's
-    # plan-vs-actual accounting, so "where did the tick go" and "how
-    # wrong were the estimates" read out of the same snapshot the
-    # benchmarks and the launcher already grep.
-    timings_last_tick: Optional[Dict] = None
-    plan_actual: Optional[Dict] = None
-    plan_mispredict_rate: float = 0.0
     _t_first_submit: Optional[float] = None
     _t_last_complete: Optional[float] = None
+    # The service whose engine, planner, corpus, bank and registry the
+    # views below read; they are computed when read, never per tick.
+    _service: Optional["MatchService"] = dataclasses.field(
+        default=None, repr=False, compare=False)
+
+    # -- views of state other components own ---------------------------------
+    @property
+    def n_shards(self) -> int:
+        """Engine row shards (mesh-resident)."""
+        return self._service.engine.n_shards
+
+    @property
+    def shard_rows(self) -> List[int]:
+        """Live rows per shard."""
+        return [int(x) for x in self._service.engine.shard_live_rows()]
+
+    @property
+    def cost_source(self) -> str:
+        """Which source prices the planner's decisions ("static" |
+        "calibrated:<digest8>"; DESIGN.md Sec. 3i)."""
+        return self._service.engine.planner.cost_source.tag
+
+    @property
+    def feedback(self) -> Dict:
+        """The planner's runtime-feedback state (observation and
+        misprediction counters, re-priced shape buckets)."""
+        return self._service.engine.planner.feedback.snapshot()
+
+    @property
+    def n_bank_launches(self) -> int:
+        """Fused bank verify dispatches ("one ingest batch = one fused
+        bank launch" is auditable here)."""
+        bank = self._service.bank
+        return bank.n_bank_launches if bank is not None else 0
+
+    @property
+    def n_bank_prefilter_launches(self) -> int:
+        bank = self._service.bank
+        return bank.n_prefilter_launches if bank is not None else 0
+
+    @property
+    def n_compactions(self) -> int:
+        """Corpus compactions triggered."""
+        return self._service.engine.corpus.n_compactions
+
+    @property
+    def bank(self) -> Optional[Dict]:
+        """``PatternBank.stats()`` of the attached bank."""
+        bank = self._service.bank
+        return bank.stats() if bank is not None else None
+
+    @property
+    def timings_last_tick(self) -> Optional[Dict]:
+        """Per-stage wall seconds summed over the latest tick's launches
+        (from the ``MatchResult.timings`` span breakdowns; DESIGN.md Sec.
+        3l): "where did the tick go"."""
+        return dict(self._service._tick_timings) or None
+
+    @property
+    def plan_actual(self) -> Optional[Dict]:
+        """The registry's plan-vs-actual accounting per (kernel, shape
+        bucket): "how wrong were the estimates"."""
+        return self._service.obs.metrics.plan_actual_summary() or None
+
+    @property
+    def plan_mispredict_rate(self) -> float:
+        return self._service.obs.metrics.mispredict_rate()
 
     @property
     def total_latency_s(self) -> float:
@@ -177,10 +221,11 @@ class ServiceStats:
         so it converges to 1.0 as the corpus grows; the shard benchmark
         asserts <= 1.1 after ingest.
         """
-        if not self.shard_rows or len(self.shard_rows) < 2:
+        rows = self.shard_rows
+        if len(rows) < 2:
             return 1.0
-        lo = min(self.shard_rows)
-        return float(max(self.shard_rows)) / lo if lo else float("inf")
+        lo = min(rows)
+        return float(max(rows)) / lo if lo else float("inf")
 
     @property
     def qps(self) -> float:
@@ -192,6 +237,7 @@ class ServiceStats:
                                    - self._t_first_submit)
 
     def snapshot(self) -> Dict[str, float]:
+        feedback, bank = self.feedback, self.bank
         return {
             "n_submitted": self.n_submitted,
             "n_completed": self.n_completed,
@@ -216,21 +262,19 @@ class ServiceStats:
             "latency_p99_s": round(self.latency_hist.quantile(0.99), 6),
             "qps": round(self.qps, 1),
             "n_shards": self.n_shards,
-            "shard_rows": list(self.shard_rows or []),
-            "shard_balance": (round(self.shard_balance, 4)
-                              if self.shard_rows else 1.0),
+            "shard_rows": self.shard_rows,
+            "shard_balance": round(self.shard_balance, 4),
             "merge_path": self.merge_path,
             "collective_bytes": self.collective_bytes,
             "cost_source": self.cost_source,
-            "misprediction_rate": (self.feedback or {}).get(
-                "misprediction_rate", 0.0),
-            "feedback": dict(self.feedback or {}),
+            "misprediction_rate": feedback.get("misprediction_rate", 0.0),
+            "feedback": dict(feedback),
             "n_bank_launches": self.n_bank_launches,
             "n_bank_prefilter_launches": self.n_bank_prefilter_launches,
             "n_bank_hits": self.n_bank_hits,
             "n_evicted_rows": self.n_evicted_rows,
             "n_compactions": self.n_compactions,
-            "bank": dict(self.bank) if self.bank is not None else None,
+            "bank": dict(bank) if bank is not None else None,
             "timings": dict(self.timings_last_tick or {}),
             "plan_actual": dict(self.plan_actual or {}),
             "plan_mispredict_rate": round(self.plan_mispredict_rate, 4),
@@ -348,14 +392,12 @@ class MatchService:
         if not (0.0 < float(compact_dead_frac) <= 1.0):
             raise ValueError("compact_dead_frac must be in (0, 1]")
         self.compact_dead_frac = float(compact_dead_frac)
-        self.stats = ServiceStats()
+        self.stats = ServiceStats(_service=self)
         self._tick_timings: Dict[str, float] = {}
         self._queue: List[_Pending] = []
         self._ingest_queue: List[Tuple[IngestTicket, np.ndarray]] = []
         self._cache: "OrderedDict[MatchQuery, MatchResult]" = OrderedDict()
         self._cache_generation = engine.corpus.generation
-        self._note_shards()
-        self._note_calibration()
 
     # -- submission -----------------------------------------------------------
     def submit(self, patterns, *, reduction=_UNSET, k=_UNSET,
@@ -576,18 +618,19 @@ class MatchService:
         n_rows = (len(first.rows) if first.rows is not None
                   else self.engine.corpus.n_rows)
         bp: Optional[BatchPlan] = None
+        tr = self.obs.tracer
         if n_q > 1 and n_rows > 0:
             # Empty subsets skip pricing: the engine answers them without
             # a launch, and the planner (rightly) rejects 0-row workloads.
-            bp = self.engine.planner.plan_batch(
-                n_rows=n_rows,
-                fragment_chars=self.engine.corpus.fragment_chars,
-                pattern_chars=first.pattern_chars, n_queries=n_q,
-                backend=first.backend, chunk_rows=first.chunk_rows,
-                predicate=first.predicate,
-                n_shards=self.engine.n_shards)
+            with tr.span("service.plan"):
+                bp = self.engine.planner.plan_batch(
+                    n_rows=n_rows,
+                    fragment_chars=self.engine.corpus.fragment_chars,
+                    pattern_chars=first.pattern_chars, n_queries=n_q,
+                    backend=first.backend, chunk_rows=first.chunk_rows,
+                    predicate=first.predicate,
+                    n_shards=self.engine.n_shards)
         if bp is not None and bp.coalesced:
-            tr = self.obs.tracer
             with tr.span("service.coalesce",
                          {"n_queries": len(grp), "n_uniq": n_q}
                          if tr.enabled else None):
@@ -599,42 +642,22 @@ class MatchService:
                 self._note_filter(batched)
                 self._note_merge(batched)
                 self._note_timings(batched)
-                for q, mem in enumerate(members):
-                    k_q = mem[0].query.k[0] if mem[0].query.k else 0
-                    res = self._scatter(batched, q, n_q, k_q)
-                    self._cache_put(mem[0].query, res)
-                    for p in mem:
-                        self._complete(p, res, cached=False)
+                with tr.span("service.complete"):
+                    for q, mem in enumerate(members):
+                        k_q = mem[0].query.k[0] if mem[0].query.k else 0
+                        res = self._scatter(batched, q, n_q, k_q)
+                        self._cache_put(mem[0].query, res)
+                        for p in mem:
+                            self._complete(p, res, cached=False)
         else:
             if n_q > 1:
                 self.stats.n_sequential_fallback += len(grp)
             for mem in members:
                 res = self._run_single(mem[0])
-                self._cache_put(mem[0].query, res)
-                for p in mem:
-                    self._complete(p, res, cached=False)
-
-    def _note_shards(self) -> None:
-        """Refresh per-shard placement stats from the engine.
-
-        Cyclic placement (DESIGN.md Sec. 3h) appends row n to shard
-        n % S -- always the shard with the fewest live rows -- so ingest
-        is balanced by construction; the snapshot makes that auditable.
-        """
-        self.stats.n_shards = self.engine.n_shards
-        self.stats.shard_rows = [
-            int(x) for x in self.engine.shard_live_rows()]
-
-    def _note_calibration(self) -> None:
-        """Refresh cost-model provenance + feedback state from the planner.
-
-        Taken per tick (like the shard stats) so a feedback re-pricing
-        that lands mid-session shows up in the next snapshot, not only at
-        construction time.
-        """
-        planner = self.engine.planner
-        self.stats.cost_source = planner.cost_source.tag
-        self.stats.feedback = planner.feedback.snapshot()
+                with tr.span("service.complete"):
+                    self._cache_put(mem[0].query, res)
+                    for p in mem:
+                        self._complete(p, res, cached=False)
 
     def _apply_ingests(self) -> None:
         """Append all pending ingest rows as one batched in-place write.
@@ -682,22 +705,13 @@ class MatchService:
                 and corpus.n_dead / corpus.n_rows >= self.compact_dead_frac):
             corpus.compact()
 
-    def _note_bank(self) -> None:
-        """Mirror bank + window counters into the stats snapshot."""
-        self.stats.n_compactions = self.engine.corpus.n_compactions
-        if self.bank is not None:
-            self.stats.n_bank_launches = self.bank.n_bank_launches
-            self.stats.n_bank_prefilter_launches = \
-                self.bank.n_prefilter_launches
-            self.stats.bank = self.bank.stats()
-
-    def _note_obs(self) -> None:
-        """Mirror per-tick service health into the metrics registry.
+    def publish_gauges(self) -> None:
+        """Set the registry's service gauges from the current state.
 
         Gauges carry the service-level facts no single span shows (queue
-        depth, hit rates, shard balance); the stats snapshot pulls the
-        registry's plan-vs-actual accounting back so estimate drift per
-        (kernel, shape-bucket) reads out of ``ServiceStats.snapshot()``.
+        depth, hit rates, shard balance).  Whoever reads the registry
+        calls this first (``launch/serve.py --metrics-every``); a tick
+        never does.
         """
         m = self.obs.metrics
         s = self.stats
@@ -709,10 +723,6 @@ class MatchService:
         m.gauge("service.collective_bytes").set(s.collective_bytes)
         m.gauge("service.n_evicted_rows").set(s.n_evicted_rows)
         m.gauge("service.n_compactions").set(s.n_compactions)
-        s.timings_last_tick = (dict(self._tick_timings)
-                               if self._tick_timings else None)
-        s.plan_actual = m.plan_actual_summary() or None
-        s.plan_mispredict_rate = m.mispredict_rate()
 
     def tick(self) -> int:
         """Drain the queues once: ingests, cache hits, grouped launches.
@@ -737,9 +747,6 @@ class MatchService:
             # ingest scan: a pattern past its deadline must not fire.
             self.bank.expire()
         self._apply_ingests()
-        self._note_shards()
-        self._note_calibration()
-        self._note_bank()
         gen = self.engine.corpus.generation
         if gen != self._cache_generation:
             self._cache.clear()
@@ -750,22 +757,22 @@ class MatchService:
         pending, self._queue = self._queue, []
         if not pending:
             self.stats.launches_last_tick = 0
-            self._note_obs()
             return 0
         before = self.stats.n_completed
         groups: "OrderedDict[Tuple, List[_Pending]]" = OrderedDict()
-        for p in pending:
-            hit = self._cache_get(p.query)
-            if hit is not None:
-                self._complete(p, hit, cached=True)
-                continue
-            # Non-coalescible (2-D / batched) queries group by query
-            # content, not ticket identity: same-tick duplicates share one
-            # launch (the `uniq` dedup in _run_group) instead of paying a
-            # full launch each.
-            key = p.group_key if p.group_key is not None else (
-                "solo", p.query)
-            groups.setdefault(key, []).append(p)
+        with self.obs.tracer.span("service.cache"):
+            for p in pending:
+                hit = self._cache_get(p.query)
+                if hit is not None:
+                    self._complete(p, hit, cached=True)
+                    continue
+                # Non-coalescible (2-D / batched) queries group by query
+                # content, not ticket identity: same-tick duplicates share
+                # one launch (the `uniq` dedup in _run_group) instead of
+                # paying a full launch each.
+                key = p.group_key if p.group_key is not None else (
+                    "solo", p.query)
+                groups.setdefault(key, []).append(p)
         for grp in groups.values():
             try:
                 self._run_group(grp)
@@ -779,5 +786,4 @@ class MatchService:
                         self._complete(p, None, cached=False, error=e)
         self.stats.launches_last_tick = (self.stats.n_launches
                                          - launches_before)
-        self._note_obs()
         return self.stats.n_completed - before

@@ -36,12 +36,20 @@ Optional ``jax.profiler`` hook: ``Tracer(profiler=True)`` additionally
 enters a ``jax.profiler.TraceAnnotation`` per span, so spans line up
 with device activity inside a captured XLA profile.  The import is
 lazy; the module itself never touches jax.
+
+While a tracer is enabled it also records a ``host.gc`` span for every
+full (generation-2) Python garbage collection, from ``gc.callbacks``:
+a collection stops the host, and on the profiler's clock the device
+idles under it.  Younger generations run every few hundred allocations
+and get no span.
 """
 
 from __future__ import annotations
 
+import gc
 import json
 import time
+import weakref
 from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple
 
 # Stage names the per-request timing breakdown aggregates over
@@ -92,12 +100,12 @@ NOOP_SPAN = _NoopSpan()
 class Span:
     """One timed stage: monotonic times, typed attrs, children.
 
-    The hot path is deliberately lean (the overhead gate in
-    ``BENCH_match_obs.json`` depends on it): one ``perf_counter`` call
-    per boundary, no wall-clock read (derived from the tracer's paired
-    epochs at export), no attrs dict unless the caller passed or set
-    one, and attribute *coercion* deferred to export -- ``set`` coerces
-    eagerly since mid-span values may be mutated later by the caller,
+    The hot path is deliberately lean (the benchmark's traced runs pay
+    it a few times per chunk): one ``perf_counter`` call per boundary,
+    no wall-clock read (derived from the tracer's paired epochs at
+    export), no attrs dict unless the caller passed or set one, and
+    attribute *coercion* deferred to export -- ``set`` coerces eagerly
+    since mid-span values may be mutated later by the caller,
     constructor attrs are coerced when serialized.
     """
 
@@ -140,12 +148,7 @@ class Span:
             stack.pop()
         if stack:
             stack.pop()
-        if stack:
-            stack[-1].children.append(self)
-        elif len(tr.roots) < tr.max_spans:
-            tr.roots.append(self)
-        else:
-            tr.n_dropped += 1
+        tr._land(self)
         return False
 
     # -- attributes ------------------------------------------------------------
@@ -213,7 +216,10 @@ class Tracer:
 
     def __init__(self, *, enabled: bool = False, profiler: bool = False,
                  max_spans: int = 100_000):
-        self.enabled = bool(enabled)
+        self._gc_hook = None
+        self._gc_fin = None
+        self._gc_t0: Optional[float] = None
+        self._gc_prof = None
         self.max_spans = int(max_spans)
         self.roots: List[Span] = []
         self.n_dropped = 0
@@ -230,6 +236,66 @@ class Tracer:
                 self._annotation = TraceAnnotation
             except Exception:
                 self._annotation = None
+        self.enabled = enabled
+
+    @property
+    def enabled(self) -> bool:
+        return self._enabled
+
+    @enabled.setter
+    def enabled(self, on: bool) -> None:
+        """Turn recording on or off; the ``host.gc`` hook is installed
+        in ``gc.callbacks`` exactly while recording is on."""
+        self._enabled = bool(on)
+        if self._enabled and self._gc_hook is None:
+            self._gc_hook = _make_gc_hook(weakref.ref(self))
+            gc.callbacks.append(self._gc_hook)
+            self._gc_fin = weakref.finalize(self, _gc_unhook, self._gc_hook)
+        elif not self._enabled and self._gc_hook is not None:
+            self._gc_fin()             # unhooks, once
+            self._gc_hook = self._gc_fin = None
+
+    def _land(self, span: Span) -> None:
+        """File a finished span under the innermost open span, else as
+        a root (bounded by ``max_spans``)."""
+        stack = self._stack
+        if stack:
+            stack[-1].children.append(span)
+        elif len(self.roots) < self.max_spans:
+            self.roots.append(span)
+        else:
+            self.n_dropped += 1
+
+    def _on_gc(self, phase: str, info: Dict[str, int]) -> None:
+        """``host.gc`` around one full collection.
+
+        The collection can start at any allocation, inside
+        ``Span.__enter__``/``__exit__`` too, so this never touches the
+        open-span stack: the span is built whole when the collection
+        stops and filed under whatever span is innermost then.  Its
+        profiler annotation spans the collection itself.
+        """
+        if info.get("generation") != 2:
+            return
+        if phase == "start":
+            self._gc_t0 = time.perf_counter()
+            if self._annotation is not None:
+                self._gc_prof = self._annotation("host.gc")
+                self._gc_prof.__enter__()
+            return
+        if self._gc_t0 is None:
+            return
+        t1 = time.perf_counter()
+        if self._gc_prof is not None:
+            self._gc_prof.__exit__(None, None, None)
+            self._gc_prof = None
+        sp = Span(self, "host.gc", {"collected": info.get("collected", 0)})
+        self._n_spans += 1
+        sp.span_id = self._n_spans
+        sp.parent_id = self._stack[-1].span_id if self._stack else None
+        sp.t0, sp.t1 = self._gc_t0, t1
+        self._gc_t0 = None
+        self._land(sp)
 
     @property
     def n_spans(self) -> int:
@@ -239,7 +305,7 @@ class Tracer:
 
     def span(self, name: str, attrs: Optional[Dict[str, Any]] = None):
         """Context manager for one stage; free no-op when disabled."""
-        if not self.enabled:
+        if not self._enabled:
             return NOOP_SPAN
         return Span(self, name, attrs)
 
@@ -323,3 +389,20 @@ class Tracer:
         with open(path, "w") as fh:
             json.dump(trace, fh)
         return len(trace["traceEvents"])
+
+
+def _make_gc_hook(ref):
+    """A ``gc.callbacks`` entry for the tracer behind ``ref``; it holds
+    the tracer weakly, so an enabled tracer can still be freed."""
+    def on_gc(phase, info):
+        tr = ref()
+        if tr is not None and tr._enabled:
+            tr._on_gc(phase, info)
+    return on_gc
+
+
+def _gc_unhook(hook) -> None:
+    try:
+        gc.callbacks.remove(hook)
+    except ValueError:
+        pass

@@ -18,6 +18,7 @@ Covers the contracts the layer is trusted for:
 
 from __future__ import annotations
 
+import gc
 import json
 import math
 import pathlib
@@ -400,7 +401,7 @@ def test_lint_catches_uncovered_dispatch(tmp_path):
     (k / "foo.py").write_text(
         "import jax.experimental.pallas as pl\n"
         "def kern(x):\n"
-        "    return pl.pallas_call(lambda r: r)(x)\n")
+        "    return pl.pallas_call(lambda r: r, name='kern')(x)\n")
     (m / "eng.py").write_text(
         "from repro.kernels import foo as _f\n"
         "def run(x):\n"
@@ -417,3 +418,229 @@ def test_lint_catches_uncovered_dispatch(tmp_path):
     good = subprocess.run([sys.executable, str(LINT), str(tmp_path)],
                           capture_output=True, text=True)
     assert good.returncode == 0, good.stderr
+
+
+def test_lint_catches_unnamed_kernel(tmp_path):
+    """A kernel's name is what the device trace shows and the roofline
+    readers match: a pallas_call must pass it as a literal."""
+    k = tmp_path / "src" / "repro" / "kernels"
+    m = tmp_path / "src" / "repro" / "match"
+    k.mkdir(parents=True)
+    m.mkdir(parents=True)
+    (m / "eng.py").write_text(
+        "from repro.kernels import foo as _f\n"
+        "def run(x, tr):\n"
+        "    with tr.span('launch'):\n"
+        "        return _f.kern(x)\n")
+    for call, ok in [("pl.pallas_call(lambda r: r)", False),
+                     ("pl.pallas_call(lambda r: r, name=NAME)", False),
+                     ("pl.pallas_call(lambda r: r, name='kern')", True),
+                     ("pl.pallas_call(lambda r: r, name='a' if x else 'b')",
+                      True)]:
+        (k / "foo.py").write_text(
+            "import jax.experimental.pallas as pl\n"
+            "NAME = 'kern'\n"
+            "def kern(x):\n"
+            f"    return {call}(x)\n")
+        proc = subprocess.run([sys.executable, str(LINT), str(tmp_path)],
+                              capture_output=True, text=True)
+        assert (proc.returncode == 0) == ok, (call, proc.stderr)
+        if not ok:
+            assert ("foo.py:4: pallas_call without a literal name="
+                    in proc.stderr)
+
+
+# -- host.gc -----------------------------------------------------------------
+
+def _well_formed(tr):
+    """Every span closed, filed under the span it names as parent, with
+    unique ids; nothing left open."""
+    assert tr._stack == []
+    seen = set()
+
+    def visit(sp, parent):
+        assert sp.t1 is not None and sp.t1 >= sp.t0
+        assert sp.parent_id == (parent.span_id if parent else None)
+        assert sp.span_id not in seen
+        seen.add(sp.span_id)
+        for ch in sp.children:
+            visit(ch, sp)
+    for r in tr.roots:
+        visit(r, None)
+    return seen
+
+
+def test_full_gc_inside_nested_spans_is_one_host_gc_child():
+    tr = Tracer(enabled=True)
+    with tr.span("a"):
+        with tr.span("b") as b:
+            gc.collect()
+            assert tr._stack[-1] is b
+    assert [c.name for c in b.children] == ["host.gc"]
+    assert b.children[0].duration_s > 0.0
+    assert len(_well_formed(tr)) == tr.n_spans == 3
+    gc.collect(0)                      # a young collection gets no span
+    assert sum(s.name == "host.gc" for s in tr.iter_spans()) == 1
+
+
+class _CollectingAnnotation:
+    """A profiler annotation that runs a full collection as it opens and
+    closes: a collection inside ``Span.__enter__`` and ``__exit__``."""
+
+    entered = 0
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        if self.name != "host.gc":
+            gc.collect()
+        _CollectingAnnotation.entered += 1
+
+    def __exit__(self, *exc):
+        if self.name != "host.gc":
+            gc.collect()
+
+
+def test_gc_inside_span_enter_and_exit_keeps_the_tree():
+    tr = Tracer(enabled=True)
+    tr._annotation = _CollectingAnnotation
+    _CollectingAnnotation.entered = 0
+    with tr.span("outer"):
+        with tr.span("inner"):
+            pass
+    names = [s.name for s in tr.iter_spans()]
+    assert names.count("host.gc") == 4 and names[:2] == ["outer", "host.gc"]
+    assert len(_well_formed(tr)) == tr.n_spans == len(names)
+    # Each collection's own annotation opened around it, beside the two
+    # spans' annotations.
+    assert _CollectingAnnotation.entered == 6
+
+
+def test_gc_at_any_allocation_keeps_the_tree():
+    tr = Tracer(enabled=True)
+    was = gc.get_threshold()
+    gc.set_threshold(1, 1, 1)
+    try:
+        for i in range(300):
+            with tr.span("a", {"i": i}):
+                with tr.span("b") as b:
+                    b.set("x", [i])
+    finally:
+        gc.set_threshold(*was)
+    assert len(_well_formed(tr)) == tr.n_spans
+    assert sum(s.name == "a" for s in tr.iter_spans()) == 300
+
+
+def test_gc_hook_only_while_enabled():
+    gc.collect()
+    n = len(gc.callbacks)
+    off = Tracer(enabled=False)
+    Observability()
+    assert len(gc.callbacks) == n and off._gc_hook is None
+    off.enabled = True
+    hook = off._gc_hook
+    assert hook in gc.callbacks
+    off.enabled = False
+    assert hook not in gc.callbacks and off._gc_hook is None
+    gc.collect()
+    assert off.n_spans == 0
+    on = Tracer(enabled=True)
+    hook = on._gc_hook
+    assert hook in gc.callbacks
+    del on
+    gc.collect()                       # a dropped tracer unhooks itself
+    assert hook not in gc.callbacks
+
+
+# -- pull, timings and the service snapshot ----------------------------------
+
+def test_pull_has_wait_and_copy_children(traced_service):
+    svc, tickets, obs = traced_service
+    pulls = [s for s in obs.tracer.iter_spans() if s.name == "pull"]
+    assert pulls
+    for p in pulls:
+        assert [c.name for c in p.children] == ["pull.wait", "pull.copy"]
+
+
+def _without(span, names):
+    """A copy of ``span``'s tree with spans named in ``names`` spliced
+    out (their children lifted to the parent)."""
+    from repro.obs import Span
+    out = Span(span.tracer, span.name, None)
+    out.t0, out.t1 = span.t0, span.t1
+
+    def lift(ch):
+        if ch.name in names:
+            return [g for c in ch.children for g in lift(c)]
+        return [_without(ch, names)]
+    out.children = [g for c in span.children for g in lift(c)]
+    return out
+
+
+def test_timings_ignore_the_non_stage_spans(traced_service):
+    svc, tickets, obs = traced_service
+    extra = {"chunk.host", "result", "filter.launch", "filter.union",
+             "pull.wait", "pull.copy", "host.gc"}
+    runs = [s for s in obs.tracer.iter_spans() if s.name == "match.run"]
+    assert any(c.name in extra for r in runs for c in r.walk())
+    for r in runs:
+        assert r.stage_seconds() == _without(r, extra).stage_seconds()
+
+
+SNAPSHOT_FIELDS = {
+    "n_submitted", "n_completed", "n_cache_hits", "n_launches",
+    "n_coalesced_launches", "n_coalesced_queries", "n_sequential_fallback",
+    "n_failed", "n_ingested_rows", "n_ingest_batches", "n_ticks",
+    "launches_last_tick", "avg_launches_per_tick", "cache_hit_rate",
+    "n_filtered_launches", "filter_hit_rate", "avg_survivor_frac",
+    "avg_latency_s", "latency_p50_s", "latency_p95_s", "latency_p99_s",
+    "qps", "n_shards", "shard_rows", "shard_balance", "merge_path",
+    "collective_bytes", "cost_source", "misprediction_rate", "feedback",
+    "n_bank_launches", "n_bank_prefilter_launches", "n_bank_hits",
+    "n_evicted_rows", "n_compactions", "bank", "timings",
+    "plan_actual", "plan_mispredict_rate"}
+
+
+def test_snapshot_reads_at_read_time_what_ticks_mirrored():
+    """The views a tick used to copy into the stats (shards, cost source,
+    feedback, bank and window counters, stage timings, plan-vs-actual)
+    read the same values from their owners when the snapshot is taken."""
+    from repro.match import (MatchEngine, MatchQuery, MatchService,
+                             PackedCorpus, PatternBank)
+    rng = np.random.default_rng(33)
+    F, P = 64, 12
+    frags = rng.integers(0, 4, (24, F), np.uint8)
+    eng = MatchEngine(PackedCorpus(frags, capacity=256),
+                      obs=Observability(spans=True))
+    bank = PatternBank(F, P, capacity=8)
+    svc = MatchService(eng, bank=bank, window_rows=30,
+                       compact_dead_frac=0.3)
+    bank.register(frags[3, 5:5 + P].copy(), threshold=P)
+    for i in range(5):
+        svc.ingest(rng.integers(0, 4, (8, F), np.uint8))
+        for j in range(3):
+            svc.submit(MatchQuery.exact(frags[(i + j) % 24, :P].copy()))
+        svc.tick()
+    snap = svc.stats.snapshot()
+    assert set(snap) == SNAPSHOT_FIELDS
+    json.dumps(snap)
+    m = eng.obs.metrics
+    assert snap["n_shards"] == 1
+    assert snap["shard_rows"] == [int(x) for x in eng.shard_live_rows()]
+    assert snap["cost_source"] == eng.planner.cost_source.tag
+    assert snap["feedback"] == eng.planner.feedback.snapshot()
+    assert snap["n_bank_launches"] == bank.n_bank_launches == 5
+    assert snap["n_bank_prefilter_launches"] == bank.n_prefilter_launches
+    assert snap["bank"] == bank.stats()
+    assert snap["n_compactions"] == eng.corpus.n_compactions > 0
+    assert snap["plan_actual"] == m.plan_actual_summary()
+    assert snap["plan_mispredict_rate"] == round(m.mispredict_rate(), 4)
+    assert set(snap["timings"]) == set(STAGES)
+    assert snap["timings"] == svc._tick_timings
+    # A tick sets no gauge; whoever reads the registry publishes them.
+    assert "service.queue_depth" not in m.gauges
+    svc.submit(MatchQuery.exact(frags[0, :P].copy()))
+    svc.publish_gauges()
+    assert m.gauge("service.queue_depth").value == 1
+    assert m.gauge("service.n_compactions").value == snap["n_compactions"]

@@ -1,5 +1,6 @@
 #!/usr/bin/env python
-"""AST lint: every pallas-dispatching engine path runs under a span.
+"""AST lint: every pallas-dispatching engine path runs under a span, and
+every kernel has a stable name.
 
 The observability contract (DESIGN.md Sec. 3l) is that no kernel launch
 escapes the trace: any code path in the match runtime that can reach a
@@ -26,7 +27,14 @@ statically, with no imports and no JAX:
    helpers like ``_chunk_scores`` stay span-free as long as each caller
    wraps them.
 
-Exit status 1 with ``file:line`` diagnostics on any uncovered dispatch.
+4. **Kernel names.**  Every ``pallas_call`` under ``src/repro/kernels/``
+   passes ``name=`` as a string literal (or a conditional between
+   literals).  The device trace names a kernel's instruction after it,
+   and the benchmark's roofline readers match those names, so a
+   refactor must not rename a kernel by accident.
+
+Exit status 1 with ``file:line`` diagnostics on any uncovered dispatch
+or unnamed kernel.
 """
 
 from __future__ import annotations
@@ -57,6 +65,32 @@ def _contains_pallas_call(fn: ast.AST) -> bool:
             if isinstance(f, ast.Name) and f.id == "pallas_call":
                 return True
     return False
+
+
+def _literal_name(expr: ast.AST) -> bool:
+    """A string constant, or a conditional whose branches all are."""
+    if isinstance(expr, ast.Constant):
+        return isinstance(expr.value, str) and bool(expr.value)
+    if isinstance(expr, ast.IfExp):
+        return _literal_name(expr.body) and _literal_name(expr.orelse)
+    return False
+
+
+def unnamed_pallas_calls(kernels_dir: Path, root: Path) -> List[str]:
+    """``file:line`` of each ``pallas_call`` without a literal ``name=``."""
+    out: List[str] = []
+    for path in sorted(kernels_dir.glob("*.py")):
+        for node in ast.walk(_parse(path)):
+            if not isinstance(node, ast.Call):
+                continue
+            f = node.func
+            if not ((isinstance(f, ast.Attribute) and f.attr == "pallas_call")
+                    or (isinstance(f, ast.Name) and f.id == "pallas_call")):
+                continue
+            if not any(kw.arg == "name" and _literal_name(kw.value)
+                       for kw in node.keywords):
+                out.append(f"{path.relative_to(root)}:{node.lineno}")
+    return out
 
 
 def _called_names(fn: ast.AST) -> Set[str]:
@@ -243,14 +277,19 @@ def main(root: Optional[Path] = None) -> int:
 
     violations = [s for s in all_sites
                   if not _site_ok(s.func_stack, s.in_span)]
-    if violations:
+    unnamed = unnamed_pallas_calls(kernels_dir, root)
+    if violations or unnamed:
         for s in violations:
             where = ".".join(s.func_stack) or "<module>"
             print(f"{s.path}:{s.line}: pallas dispatch `{s.callee}` in "
                   f"`{where}` is not under a tracer span (and not every "
                   f"call site of `{where}` is)", file=sys.stderr)
+        for site in unnamed:
+            print(f"{site}: pallas_call without a literal name=",
+                  file=sys.stderr)
         print(f"lint_obs_spans: {len(violations)} uncovered dispatch "
-              f"site(s) of {len(all_sites)}", file=sys.stderr)
+              f"site(s) of {len(all_sites)}, {len(unnamed)} unnamed "
+              "kernel(s)", file=sys.stderr)
         return 1
     print(f"lint_obs_spans: OK -- {len(all_sites)} pallas dispatch sites "
           f"across {match_dir.relative_to(root)} all run under spans "
