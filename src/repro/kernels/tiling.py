@@ -4,12 +4,13 @@ The match kernels are row-elementwise: every program instance computes a
 pure function of its row tile, so the *dispatch* tile (the BlockSpec row
 count) is a free parameter as long as it divides the padded row count.
 The public padding contracts stay at the fine tiles (``ROW_TILE`` = 8,
-``FILTER_ROW_TILE`` = 128) -- callers pad to those -- but launching one
-program per fine tile is ruinous at scale: a 1M-row corpus is 131072 grid
-steps for the SWAR kernel, and per-step overhead (a few us on TPU, ~400us
-in interpret mode) dominates the arithmetic.  Coarsening the dispatch
-tile amortizes the launch: same ops per row, bit-identical output,
-O(grid) overhead shrunk by the coarsening factor.
+``FILTER_ROW_TILE`` = 128, ``SIG_ROW_TILE`` = 1024) -- callers pad to
+those -- but launching one program per fine tile is ruinous at scale: a
+1M-row corpus is 131072 grid steps for the SWAR kernel, and per-step
+overhead (a few us on TPU, ~400us in interpret mode) dominates the
+arithmetic.  Coarsening the dispatch tile amortizes the launch: same ops
+per row, bit-identical output, O(grid) overhead shrunk by the coarsening
+factor.
 
 The tile grows by doubling (keeps divisibility trivially) until it stops
 dividing the row count, exceeds the VMEM block budget, or hits the row

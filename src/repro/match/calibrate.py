@@ -200,11 +200,11 @@ def _build_call(kernel: str, shape: Mapping, interpret: bool,
     if kernel == "filter":
         wb = int(shape["sig_words"])
         rows = jax.numpy.asarray(
-            rng.integers(0, 2**32, (R, wb), dtype=np.uint32))
-        qsig = jax.numpy.asarray(
-            rng.integers(0, 2**32, (1, wb), dtype=np.uint32))
+            rng.integers(0, 2**32, (wb, R), dtype=np.uint32))
+        qsig, slacks = (jax.numpy.asarray(a) for a in _fq.pattern_operands(
+            rng.integers(0, 2**32, (1, wb), dtype=np.uint32), [4]))
         analytic = analytic_filter_seconds(roofline, R, wb, 1)
-        return (lambda: _fq.filter_qgram(rows, qsig, slack=4,
+        return (lambda: _fq.filter_qgram(rows, qsig, slacks,
                                          interpret=interpret)), analytic
 
     F, P = int(shape["F"]), int(shape["P"])

@@ -272,18 +272,21 @@ class PackedCorpus:
         return (self.n_shards > 1 and self._mesh is not None
                 and jax.process_count() > 1)
 
-    def _row_sharding(self) -> NamedSharding:
-        return NamedSharding(self._mesh, PartitionSpec(self._row_axes))
+    def _row_sharding(self, axis: int = 0) -> NamedSharding:
+        """Rows sharded over the row axes along ``axis`` of a form."""
+        return NamedSharding(self._mesh,
+                             PartitionSpec(*(None,) * axis, self._row_axes))
 
-    def _place(self, arr) -> jnp.ndarray:
+    def _place(self, arr, axis: int = 0) -> jnp.ndarray:
         """Device placement: NamedSharding over the row axes when sharded.
 
-        Multi-controller, ``arr`` is a replicated *host* array (identical
-        on every process); each process materializes only the shard
-        blocks its own devices hold.
+        ``axis`` is the form's row axis (0 for the row-major corpus forms,
+        1 for the lane-dense signature form).  Multi-controller, ``arr``
+        is a replicated *host* array (identical on every process); each
+        process materializes only the shard blocks its own devices hold.
         """
         if self.n_shards > 1 and self._mesh is not None:
-            ns = self._row_sharding()
+            ns = self._row_sharding(axis)
             if jax.process_count() > 1:
                 a = np.asarray(arr)
                 return jax.make_array_from_callback(
@@ -291,34 +294,37 @@ class PackedCorpus:
             return jax.device_put(arr, ns)
         return jnp.asarray(arr)
 
-    def _grow_form_rows(self, form: jnp.ndarray, c_pad: int) -> jnp.ndarray:
-        """Zero-extend a device form to ``c_pad`` rows, per shard.
+    def _grow_form_rows(self, form: jnp.ndarray, c_pad: int,
+                        axis: int = 0) -> jnp.ndarray:
+        """Zero-extend a device form to ``c_pad`` rows along ``axis``, per
+        shard.
 
-        Single-shard: plain concat.  Sharded: the extension happens
-        *inside* each shard's block -- reshape (S, J_old, w), pad slot
-        axis, reshape back -- so every resident row keeps its shard and
+        Single-shard: plain pad.  Sharded: the extension happens *inside*
+        each shard's block -- split the row axis into (S, J_old), pad the
+        slot axis, merge back -- so every resident row keeps its shard and
         slot (growth stays in place per shard) and the result re-places
         onto the same NamedSharding.  Multi-controller the same program
         runs jitted (growth events are O(log capacity) per lifetime, so
         a fresh trace per doubling is fine): eager reshape of a
         non-addressable array would throw.
         """
-        S, w = self.n_shards, form.shape[1]
+        S = self.n_shards
+        pad = [(0, 0)] * form.ndim
         if S == 1:
-            grown = jnp.concatenate(
-                [form, jnp.zeros((c_pad - form.shape[0], w), form.dtype)], 0)
-            return self._place(grown)
-        j_old, j_new = form.shape[0] // S, c_pad // S
+            pad[axis] = (0, c_pad - form.shape[axis])
+            return self._place(jnp.pad(form, pad), axis)
+        j_old, j_new = form.shape[axis] // S, c_pad // S
 
         def grow(f):
-            f3 = f.reshape(S, j_old, w)
-            f3 = jnp.concatenate(
-                [f3, jnp.zeros((S, j_new - j_old, w), f.dtype)], 1)
-            return f3.reshape(S * j_new, w)
+            head, tail = f.shape[:axis], f.shape[axis + 1:]
+            f3 = f.reshape(*head, S, j_old, *tail)
+            f3 = jnp.pad(f3, pad[:axis] + [(0, 0), (0, j_new - j_old)]
+                         + pad[axis + 1:])
+            return f3.reshape(*head, S * j_new, *tail)
 
         if self._multiprocess:
-            return jax.jit(grow, out_shardings=self._row_sharding())(form)
-        return self._place(grow(form))
+            return jax.jit(grow, out_shardings=self._row_sharding(axis))(form)
+        return self._place(grow(form), axis)
 
     def _grow_form_cols(self, form: jnp.ndarray, grow: int) -> jnp.ndarray:
         """Zero-extend a device form's word/column axis, in place per row."""

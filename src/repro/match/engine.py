@@ -428,14 +428,13 @@ class CompiledMatch:
             if self.plan.strategy == "filter":
                 with tr.span("filter") as sp_fil:
                     t0 = time.perf_counter()
-                    flags = engine._run_filter(self, R)
+                    sel = engine._run_filter(self, R)
                     t_fil = time.perf_counter() - t0
-                    with tr.span("filter.union"):
-                        sel = np.flatnonzero(flags).astype(np.int64)
-                        if dead_full is not None:
-                            # Tombstoned rows can survive the signature
-                            # test but must not reach the verify stage
-                            # (nor the hits).
+                    if dead_full is not None:
+                        # Tombstoned rows can survive the signature test
+                        # but must not reach the verify stage (nor the
+                        # hits).
+                        with tr.span("filter.union"):
                             sel = sel[~dead_full[sel]]
                     survivor_frac = len(sel) / R
                     if tr.enabled:
@@ -999,51 +998,52 @@ class MatchEngine:
         return ctx, ops
 
     def _run_filter(self, cm: CompiledMatch, n_rows: int) -> np.ndarray:
-        """Filter stage: (n_rows,) bool candidate flags for one query.
+        """Filter stage: ascending ids of the candidate rows of one query.
 
-        One ``filter_qgram`` dispatch per pattern; a row survives if any
-        pattern's test admits it (the batched union).  Signatures stream
-        from the device-resident index -- the exact scan's data is never
-        touched for pruned rows.
+        One ``filter_qgram`` dispatch for the whole group: each row tile
+        of the resident signatures is read once and tested against every
+        pattern, and a row survives if any pattern admits it (the batched
+        union).  Signatures stream from the device-resident index -- the
+        exact scan's data is never touched for pruned rows.
 
         Sharded engines run the kernel per shard under ``shard_map`` over
         the sharded signature form: each shard tests its own rows (the
-        q-gram lemma is a per-row property, so it holds per shard), the
-        per-pattern union happens device-side, and the cross-shard
-        survivor union is a device all_gather + un-permute through the
+        q-gram lemma is a per-row property, so it holds per shard), and
+        the cross-shard survivor union is a device all_gather through the
         merge layer -- the host receives only the final replicated
-        bitmap, at any process count.
+        bitmap, one bit per row, at any process count.
         """
         ops = cm._filter_ops
         merger = self.merger
         if cm._filter_dev is None:
-            # Multi-controller: keep the tiny query signatures as host
+            # Multi-controller: keep the tiny pattern operands as host
             # arrays (identical everywhere); the jitted dispatch places
             # them replicated per its in_specs.
-            cm._filter_dev = (np.asarray(ops.qsig_words)
-                              if merger.multiprocess
-                              else jnp.asarray(ops.qsig_words))
+            dev = _fq.pattern_operands(ops.qsig_words, ops.slacks)
+            cm._filter_dev = (dev if merger.multiprocess
+                              else tuple(jnp.asarray(a) for a in dev))
         # The kernel tests the signature form's whole extent (reserved
         # rows are zero and their flags are dropped), so its compiled
         # shape follows the capacity, not the live row count.
         rows = self.index.signatures()
         tr = self.obs.tracer
-        flags = None
-        for qi in range(ops.qsig_words.shape[0]):
-            def filter_launch(r, q, _slack=ops.slacks[qi]):
-                return _fq.filter_qgram(r, q, slack=_slack,
-                                        interpret=self.interpret)
-            with tr.span("filter.launch"):
-                f = self._shard_wrap(filter_launch,
-                                     ("filter", ops.slacks[qi]))(
-                    rows, cm._filter_dev[qi:qi + 1])
-            if flags is None:
-                flags = f
-                continue
-            with tr.span("filter.union"):
-                flags = merger.or_(flags, f)
+
+        def filter_launch(r, q, s):
+            return _fq.filter_qgram(r, q, s, interpret=self.interpret)
+        with tr.span("filter.launch"):
+            flags = self._shard_wrap(filter_launch, ("filter",), rep_args=2,
+                                     row_axis=1)(rows, *cm._filter_dev)
+        metrics = self.obs.metrics
+        metrics.counter("filter.dispatches").inc()
+        metrics.counter("filter.patterns").inc(len(ops.slacks))
         with tr.span("filter.union"):
-            return merger.survivor_union(flags, n_rows)
+            # The cross-shard union is the device-side all_gather; the
+            # host receives one bit per row and maps each shard's slots
+            # to logical rows.
+            sel = _fq.survivor_rows(
+                merger.pull(flags, kind="reduced", axis=1),
+                self.index.sig_words, merger.n_shards)
+            return sel[:np.searchsorted(sel, n_rows)]
 
     def plan(self, patterns, *, backend=_UNSET, mode=_UNSET, rows=_UNSET,
              chunk_rows=_UNSET) -> Plan:
@@ -1056,11 +1056,12 @@ class MatchEngine:
 
     # -- kernel dispatch (one chunk, pure device) -----------------------------
     def _shard_wrap(self, call, cache_key, row_args: int = 1,
-                    rep_args: int = 1):
+                    rep_args: int = 1, row_axis: int = 0):
         """``call`` as one jitted ``shard_map`` launch over the row axes.
 
         ``call`` takes ``row_args`` row-sharded operands, then
-        ``rep_args`` replicated ones.  Launches are cached by
+        ``rep_args`` replicated ones; operands and output hold their rows
+        along ``row_axis``.  Launches are cached by
         ``cache_key``, which must name everything ``call`` closes over: a
         fresh closure per chunk would trace and compile every chunk
         again.  Jitted at any process count (multi-controller, eager
@@ -1071,7 +1072,8 @@ class MatchEngine:
             return call
         fn = self._launch_cache.get(cache_key)
         if fn is None:
-            spec = PartitionSpec(self._row_axes if len(self._row_axes) > 1
+            spec = PartitionSpec(*(None,) * row_axis,
+                                 self._row_axes if len(self._row_axes) > 1
                                  else self._row_axes[0])
             fn = jax.jit(jax.shard_map(
                 call, mesh=self.mesh,

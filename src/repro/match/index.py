@@ -9,7 +9,9 @@ exact matching.  This module is that stage for the TPU engine:
 * ``CorpusIndex`` maintains, per corpus row, a **B-bit q-gram occurrence
   signature**: every q-gram (q consecutive 2-bit characters) of the row is
   hashed to one of B bits and OR'd in.  Signatures are packed as uint32
-  words and kept device-resident alongside the corpus's SWAR/one-hot forms
+  words, one column of a ``(Wb, rows)`` form per row (rows ride the
+  filter kernel's lanes), and kept device-resident alongside the corpus's
+  SWAR/one-hot forms
   -- same lazy-pack-once protocol, same incremental row splices
   (``append_rows`` / ``set_rows`` index only the touched rows; pack
   counters stay flat), same generation discipline (the index never stores
@@ -255,7 +257,7 @@ class CorpusIndex:
         self.q = q
         self.n_bits = n_bits
         self.sig_words = n_bits // 32
-        self._sigs: Optional[jnp.ndarray] = None     # (S_pad, Wb) uint32
+        self._sigs: Optional[jnp.ndarray] = None     # (Wb, S_pad) uint32
         self._row_bits = np.zeros(corpus.capacity, np.int32)
         # Multi-controller: per-row distinct-bit counts live on device
         # ((S_pad, 1) int32, same cyclic layout as the signatures) --
@@ -280,18 +282,16 @@ class CorpusIndex:
     # -- geometry --------------------------------------------------------------
     @property
     def _rows_padded(self) -> int:
-        """Device-form row count: per-shard capacity padded to the filter
-        row tile.
+        """Device-form row count: per-shard capacity padded for the filter
+        kernel (``filter_qgram.padded_rows``).
 
         The signature form mirrors the corpus's cyclic row layout (same
-        shard for every logical row) but pads each shard's slot count to
-        ``FILTER_ROW_TILE`` independently -- its stride ``Jf`` is
-        therefore generally larger than the corpus forms' ``J``.
+        shard for every logical row) but pads each shard's slot count
+        independently -- its stride ``Jf`` is therefore generally larger
+        than the corpus forms' ``J``.
         """
-        tile = _fq.FILTER_ROW_TILE
         s = self.corpus.n_shards
-        j = self.corpus.capacity_padded // s
-        return s * (-(-j // tile) * tile)
+        return s * _fq.padded_rows(self.corpus.capacity_padded // s)
 
     @property
     def shard_stride(self) -> int:
@@ -300,7 +300,8 @@ class CorpusIndex:
 
     # -- residency -------------------------------------------------------------
     def signatures(self) -> jnp.ndarray:
-        """(S_pad, Wb) uint32 device-resident row signatures.
+        """(Wb, S_pad) uint32 device-resident row signatures, one column
+        per row (rows on the lanes, the filter kernel's layout).
 
         First call packs the live rows on the host (one event; reserved
         and padding rows are all-zero); later calls reuse the cached
@@ -317,7 +318,7 @@ class CorpusIndex:
                     n = self.corpus.n_rows
                     s = self.corpus.n_shards
                     stride = self.shard_stride
-                    words = np.zeros((self._rows_padded, self.sig_words),
+                    words = np.zeros((self.sig_words, self._rows_padded),
                                      np.uint32)
                     # Chunked pack (bounded occupancy temporary) straight
                     # into the cyclic physical layout the corpus forms use.
@@ -326,10 +327,10 @@ class CorpusIndex:
                         live, counts = row_signatures(
                             self.corpus.fragments[b0:b1], self.q,
                             self.n_bits)
-                        words[_sharding.cyclic_physical_rows(
-                            np.arange(b0, b1), s, stride)] = live
+                        words[:, _sharding.cyclic_physical_rows(
+                            np.arange(b0, b1), s, stride)] = live.T
                         self._row_bits[b0:b1] = counts
-                    self._sigs = self.corpus._place(words)
+                    self._sigs = self.corpus._place(words, axis=1)
             self.sig_pack_count += 1
             self.corpus.obs.metrics.counter("corpus.packs").inc()
         return self._sigs
@@ -351,7 +352,7 @@ class CorpusIndex:
         def pack(s):
             blk = blocks.get(s)
             if blk is None:
-                words = np.zeros((Jf, self.sig_words), np.uint32)
+                words = np.zeros((self.sig_words, Jf), np.uint32)
                 counts = np.zeros((Jf, 1), np.int32)
                 frag_s = self.corpus._frags[s::S]
                 live_s = max(0, (n - s + S - 1) // S)
@@ -359,16 +360,15 @@ class CorpusIndex:
                     b1 = min(b0 + _BUILD_CHUNK_ROWS, live_s)
                     w, c = row_signatures(frag_s[b0:b1], self.q,
                                           self.n_bits)
-                    words[b0:b1] = w
+                    words[:, b0:b1] = w.T
                     counts[b0:b1, 0] = c
                 blocks[s] = blk = (words, counts)
             return blk
-        ns = self.corpus._row_sharding()
         self._sigs = jax.make_array_from_callback(
-            (S * Jf, self.sig_words), ns,
-            lambda idx: pack((idx[0].start or 0) // Jf)[0])
+            (self.sig_words, S * Jf), self.corpus._row_sharding(1),
+            lambda idx: pack((idx[1].start or 0) // Jf)[0])
         self._bits_dev = jax.make_array_from_callback(
-            (S * Jf, 1), ns,
+            (S * Jf, 1), self.corpus._row_sharding(),
             lambda idx: pack((idx[0].start or 0) // Jf)[1])
         self._dcache = None
 
@@ -380,12 +380,13 @@ class CorpusIndex:
             words, counts = row_signatures(rows, self.q, self.n_bits)
             s = self.corpus.n_shards
             if s == 1:
-                self._sigs = self._sigs.at[start:start + n, :].set(
-                    jnp.asarray(words))
+                self._sigs = self._sigs.at[:, start:start + n].set(
+                    jnp.asarray(words.T))
             elif self.corpus._multiprocess:
                 phys = _sharding.cyclic_physical_rows(
                     np.arange(start, start + n), s, self.shard_stride)
-                self._sigs = _merge.scatter_rows(self._sigs, phys, words)
+                self._sigs = _merge.scatter_rows(self._sigs, phys, words.T,
+                                                 axis=1)
                 if self._bits_dev is not None:
                     self._bits_dev = _merge.scatter_rows(
                         self._bits_dev, phys,
@@ -394,7 +395,8 @@ class CorpusIndex:
             else:
                 phys = jnp.asarray(_sharding.cyclic_physical_rows(
                     np.arange(start, start + n), s, self.shard_stride))
-                self._sigs = self._sigs.at[phys, :].set(jnp.asarray(words))
+                self._sigs = self._sigs.at[:, phys].set(
+                    jnp.asarray(words.T))
             self._row_bits[start:start + n] = counts
             self.row_update_count += n
 
@@ -407,11 +409,12 @@ class CorpusIndex:
                  np.zeros(cap - self._row_bits.shape[0], np.int32)])
         if self._sigs is not None:
             pad = self._rows_padded
-            if self._sigs.shape[0] < pad:
+            if self._sigs.shape[1] < pad:
                 # Per-shard zero-extension through the corpus's layout
                 # helper: rows keep their shard and slot, placement is
                 # re-applied.
-                self._sigs = self.corpus._grow_form_rows(self._sigs, pad)
+                self._sigs = self.corpus._grow_form_rows(self._sigs, pad,
+                                                         axis=1)
                 if self._bits_dev is not None:
                     self._bits_dev = self.corpus._grow_form_rows(
                         self._bits_dev, pad)

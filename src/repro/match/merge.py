@@ -61,18 +61,11 @@ SCORE_SENTINEL = np.int32(-1)
 # shards multi-controller).  Every process packs the touched rows (tiny,
 # identical host work by SPMD discipline); XLA updates only the
 # addressable slots.
-@jax.jit
-def scatter_rows(a, i, v):
-    return a.at[i, :].set(v)
-
-
-# A jitted program is named after its function (``jit_or``, not
-# ``jit__lambda``): that name is how the device trace tells merges apart.
-def _or(x, y):
-    return x | y
-
-
-_or.__name__ = _or.__qualname__ = "or"
+@functools.partial(jax.jit, static_argnames=("axis",))
+def scatter_rows(a, i, v, axis: int = 0):
+    """Rows ``i`` of a form that holds its rows along ``axis``, set to
+    ``v``."""
+    return a.at[(slice(None),) * axis + (i,)].set(v)
 
 
 def _take_rows(a, i):
@@ -166,39 +159,41 @@ class ShardMerger:
             return np.asarray(x)
         return x
 
-    def _replicator(self, unpermute: bool):
-        fn = self._rep_fns.get(unpermute)
+    def _replicator(self, unpermute: bool, axis: int):
+        fn = self._rep_fns.get((unpermute, axis))
         if fn is None:
             S, axes = self.n_shards, self.axes
             def body(x):
-                g = jax.lax.all_gather(x, axes, axis=0, tiled=True)
+                g = jax.lax.all_gather(x, axes, axis=axis, tiled=True)
                 if unpermute:
                     # Physical (shard-major) -> logical order, on device.
-                    R = g.shape[0]
-                    g = g.reshape(S, R // S, *g.shape[1:]).swapaxes(
-                        0, 1).reshape(R, *g.shape[1:])
+                    g = _sharding.cyclic_unpermute(g, S)
                 return g
+            spec = PartitionSpec(*(None,) * axis, *self._spec)
             fn = jax.jit(jax.shard_map(
-                body, mesh=self.mesh, in_specs=(self._spec,),
+                body, mesh=self.mesh, in_specs=(spec,),
                 out_specs=PartitionSpec(), check_vma=False))
-            self._rep_fns[unpermute] = fn
+            self._rep_fns[(unpermute, axis)] = fn
         return fn
 
     def pull(self, x, *, unpermute: bool = False,
-             kind: str = "reduced") -> np.ndarray:
+             kind: str = "reduced", axis: int = 0) -> np.ndarray:
         """Device value -> host ndarray, collectively replicated first.
 
-        Row-sharded inputs are all-gathered (and un-permuted to logical
-        row order when asked) under ``shard_map`` before the host sees a
-        byte; replicated/local inputs pull directly.  ``kind`` buckets
-        the transfer accounting ("reduced" state vs. score "block").
+        Row-sharded inputs (rows along ``axis``) are all-gathered (and
+        un-permuted to logical row order when asked, which needs rows
+        along axis 0) under ``shard_map`` before the host sees a byte;
+        replicated/local inputs pull directly.  ``kind`` buckets the
+        transfer accounting ("reduced" state vs. score "block").
         """
+        if unpermute and axis:
+            raise ValueError("unpermute needs rows along axis 0")
         tr = self.obs.tracer
         with tr.span("pull",
                      {"kind": kind} if tr.enabled else None) as sp:
             sharded = self._sharded(x)
             if sharded:
-                x = self._replicator(unpermute)(x)
+                x = self._replicator(unpermute, axis)(x)
                 self.n_collectives += 1
                 self.collective_bytes += (int(x.nbytes)
                                           * (self.n_shards - 1)) \
@@ -254,10 +249,6 @@ class ShardMerger:
         with tr.span("merge", {"op": "hot_mask"} if tr.enabled else None):
             return self._jit("hot", build)(scores,
                                            np.asarray(thr_int, np.int32))
-
-    def or_(self, a, b):
-        """Jitted elementwise OR (filter flag union across patterns)."""
-        return self._jit("or", lambda: jax.jit(_or))(a, b)
 
     def gather_rows(self, arr, idx: np.ndarray):
         """Rows ``idx`` of a (possibly row-sharded) array, replicated.
@@ -419,13 +410,3 @@ class ShardMerger:
         kk = min(int(k), int(n_alive))
         return rows[:kk], scores[:kk]
 
-    # -- filter survivor union -------------------------------------------------
-    def survivor_union(self, flags, n_rows: int) -> np.ndarray:
-        """(S*jn, 1) per-shard candidate flags -> (n_rows,) logical bool.
-
-        The cross-shard union is the device-side all_gather (+ device
-        un-permute back to logical row order); the host only receives
-        the final replicated bitmap.
-        """
-        out = self.pull(flags, unpermute=True, kind="reduced")
-        return out[:n_rows, 0].astype(bool)

@@ -34,6 +34,7 @@ from typing import Optional
 from repro.core.tech import (DISPATCH_OVERHEAD_S, REF_CALL_OVERHEAD_S,
                              TPU_V5E, CostSource, StaticCostSource,
                              TPURoofline)
+from repro.kernels import filter_qgram as _fq
 from repro.kernels import match_mxu as _mxu
 from repro.kernels import match_swar as _swar
 from repro.match.feedback import FeedbackStore, kernel_key
@@ -59,7 +60,7 @@ VPU_SLOWDOWN = 64
 # ref backend sanely against the kernels when pricing batches.
 REF_OPS_PER_S = 1e9
 # Q-gram filter stage (filter_qgram kernel): and/not + full SWAR popcount
-# + compare per signature word.
+# + compare per signature word and pattern.
 FILTER_OPS_PER_WORD = 18
 
 
@@ -114,9 +115,12 @@ def analytic_ref_seconds(roofline: TPURoofline, R: int, L: int, P: int,
 
 def analytic_filter_seconds(roofline: TPURoofline, R: int, sig_words: int,
                             n_queries: int = 1) -> float:
-    """Roofline seconds for Q filter-kernel dispatches over R signatures."""
-    ops = n_queries * R * sig_words * FILTER_OPS_PER_WORD
-    bytes_hbm = n_queries * (R * sig_words * 4 + R * 4)
+    """Roofline seconds for one filter dispatch testing Q patterns against
+    R signatures: the signatures are read once, each of the kernel's
+    ``pattern_pad(Q)`` patterns (pads included) adds VPU work, and one
+    flag bit per row is written."""
+    ops = _fq.pattern_pad(n_queries) * R * sig_words * FILTER_OPS_PER_WORD
+    bytes_hbm = R * sig_words * 4 + R / 8
     t_compute = ops / (roofline.peak_bf16_flops / VPU_SLOWDOWN)
     t_mem = bytes_hbm / roofline.hbm_bw
     return max(t_compute, t_mem)
@@ -199,7 +203,7 @@ class FilterContext:
     """
 
     sig_words: int              # uint32 signature words per row
-    n_queries: int              # filter-kernel dispatches (1 per pattern)
+    n_queries: int              # patterns of the one filter dispatch
     prunable: bool              # every query can exclude rows
     survivor_frac: float        # estimated post-filter row fraction
     force: bool = False         # query hint filter=True: skip the pricing
@@ -309,16 +313,16 @@ class Planner:
 
     def filter_seconds(self, R: int, sig_words: int, n_queries: int = 1,
                        *, base: bool = False) -> float:
-        """Q filter-kernel dispatches over R row signatures.
+        """One filter-kernel dispatch of Q patterns over R row signatures.
 
-        Each dispatch reads ``sig_words`` uint32 per row plus the query
-        signature, does a handful of integer ops per word on the VPU, and
+        The dispatch reads ``sig_words`` uint32 per row once, does a
+        handful of integer ops per word and pattern on the VPU, and
         writes one flag per row -- orders of magnitude less data touched
         than the exact scan, which is the whole point of the stage.
         """
         analytic = analytic_filter_seconds(self.roofline, R, sig_words,
                                            n_queries)
-        return self._price("filter", analytic, n_queries,
+        return self._price("filter", analytic, 1,
                            R, sig_words, n_queries, base)
 
     def mxu_seconds(self, R: int, L: int, P: int, Q: int = 1,

@@ -104,11 +104,23 @@ def test_mxu_compiles(one_chip, P, Q):
                 ((p_chars * 4, q_pad), jnp.bfloat16))
 
 
-@pytest.mark.parametrize("R", [1 << 14, 1 << 20])
+@pytest.mark.parametrize("R", [1 << 14, 1 << 20, fq.padded_rows(1585712)])
 def test_filter_qgram_compiles(one_chip, R):
-    compile_for(one_chip,
-                lambda s, q: fq.filter_qgram(s, q, slack=4),
-                ((R, WB), jnp.uint32), ((1, WB), jnp.uint32))
+    Q = 16
+    compile_for(one_chip, fq.filter_qgram,
+                ((WB, R), jnp.uint32), ((Q, WB, 1), jnp.uint32),
+                ((Q, 1, 1), jnp.int32))
+
+
+@pytest.mark.parametrize("Q", [4096, 65536])
+def test_filter_qgram_compiles_large_groups(one_chip, Q):
+    # Patterns stream through VMEM in fixed blocks, so any group size
+    # compiles at the cell's row count (a group resident whole would need
+    # 4 KiB of VMEM per pattern and operand: 256 MiB at Q = 65536).
+    R = fq.padded_rows(1585712)
+    compile_for(one_chip, fq.filter_qgram,
+                ((WB, R), jnp.uint32), ((Q, WB, 1), jnp.uint32),
+                ((Q, 1, 1), jnp.int32))
 
 
 @pytest.mark.parametrize("Q,D", [(1024, 64), (4096, 8)])

@@ -23,8 +23,10 @@ import pytest
 
 from repro.core import encoding
 from repro.core.matcher import sliding_scores, sliding_scores_masks
-from repro.kernels.filter_qgram import (FILTER_ROW_TILE, filter_qgram,
-                                        filter_qgram_ref)
+from repro.kernels.filter_qgram import (FILTER_BLOCK_ROWS, SIG_ROW_TILE,
+                                        filter_qgram, filter_qgram_ref,
+                                        padded_rows, pattern_operands,
+                                        survivor_rows)
 from repro.match import (CorpusIndex, MatchEngine, MatchQuery,
                          MatchService, PackedCorpus, Planner,
                          build_query_filter)
@@ -97,23 +99,151 @@ class TestSignatures:
         assert abs(binom_cdf(5, 10, 0.5) - 0.623046875) < 1e-9
 
 
+def union_ref(sigs_cols, qsig_words, slacks):
+    """OR of ``filter_qgram_ref`` over a group's patterns ((R,) int32)."""
+    rows = np.ascontiguousarray(np.asarray(sigs_cols).T)
+    out = np.zeros(rows.shape[0], np.int32)
+    for q, s in zip(qsig_words, slacks):
+        out |= filter_qgram_ref(rows, q[None, :], s)
+    return out
+
+
+def run_filter(sigs_cols, qsig_words, slacks):
+    """The kernel's survivor ids for one group (int8 flags, 1 bit/row)."""
+    q, s = pattern_operands(qsig_words, slacks)
+    flags = np.asarray(filter_qgram(sigs_cols, q, s, interpret=True))
+    assert flags.dtype == np.int8
+    assert flags.shape == (1, sigs_cols.shape[1] // 8)
+    return survivor_rows(flags, sigs_cols.shape[0])
+
+
+def edge_group(Q, R, seed):
+    """Random group plus rows at tile and chunk edges on its slack edge."""
+    rng = np.random.default_rng(seed)
+    # Dense-ish row signatures and sparse-ish pattern bits, so absent
+    # counts straddle the slacks and both outcomes occur.
+    sigs = (rng.integers(0, 2**32, (8, R), dtype=np.uint32)
+            | rng.integers(0, 2**32, (8, R), dtype=np.uint32))
+    qsig = (rng.integers(0, 2**32, (Q, 8), dtype=np.uint32)
+            & rng.integers(0, 2**32, (Q, 8), dtype=np.uint32))
+    slacks = rng.integers(-3, 12, Q).tolist()
+    slacks[0] = s0 = max(slacks[0], 0)
+    # Pattern 0 lacks exactly s0 of its bits in the even edge columns (so
+    # it admits them) and s0 + 1 in the odd ones.
+    need = np.unpackbits(qsig[0].view(np.uint8), bitorder="little")
+    for col in (0, 127, 128, 511, 512, 1023, 1024, 2047, 2048, R - 1):
+        if col >= R:
+            continue
+        keep = np.ones_like(need)
+        keep[np.flatnonzero(need)[:s0 + col % 2]] = 0
+        sigs[:, col] = np.packbits(keep, bitorder="little").view(np.uint32)
+    return sigs, qsig, slacks
+
+
 class TestFilterKernel:
     def test_kernel_matches_ref(self):
+        # One pattern at each slack, negative (unsatisfiable) included.
         rng = np.random.default_rng(2)
-        sigs = rng.integers(0, 2**32, (FILTER_ROW_TILE * 2, 8),
+        sigs = rng.integers(0, 2**32, (8, SIG_ROW_TILE * 2),
                             dtype=np.uint32)
         qsig = rng.integers(0, 2**32, (1, 8), dtype=np.uint32)
         for slack in (0, 3, 17, -1):
-            got = np.asarray(filter_qgram(sigs, qsig, slack=slack,
-                                          interpret=True))[:, 0]
             np.testing.assert_array_equal(
-                got, filter_qgram_ref(sigs, qsig, slack))
+                run_filter(sigs, qsig, [slack]),
+                np.flatnonzero(filter_qgram_ref(
+                    np.ascontiguousarray(sigs.T), qsig, slack)))
+
+    @pytest.mark.parametrize("Q,R", [
+        (1, SIG_ROW_TILE * 2), (3, SIG_ROW_TILE),
+        (8, SIG_ROW_TILE * 3), (16, SIG_ROW_TILE * 4),
+        (17, FILTER_BLOCK_ROWS * 2), (100, SIG_ROW_TILE)])
+    def test_group_matches_union_of_ref(self, Q, R):
+        # Mixed slacks (negative ones too), pad patterns up to Q_pad, rows
+        # at tile and chunk edges, and (Q = 100) two pattern blocks OR-ed
+        # into one row tile: bit-identical to the OR of the oracle.
+        sigs, qsig, slacks = edge_group(Q, R, seed=Q)
+        want = union_ref(sigs, qsig, slacks)
+        np.testing.assert_array_equal(run_filter(sigs, qsig, slacks),
+                                      np.flatnonzero(want))
+        assert 0 < want.sum() < R
+
+    def test_pad_patterns_never_pass(self):
+        # Three real patterns pad to eight; the pads' all-zero signatures
+        # would pass every row at any slack >= 0, so they carry -1.
+        q, s = pattern_operands(np.zeros((3, 8), np.uint32), [-1, -1, -1])
+        assert q.shape == (8, 8, 1) and s.shape == (8, 1, 1)
+        assert (s[3:] == -1).all() and not q[3:].any()
+        sigs = np.zeros((8, SIG_ROW_TILE), np.uint32)
+        assert run_filter(sigs, np.zeros((3, 8), np.uint32),
+                          [-1, -1, -1]).size == 0
+        np.testing.assert_array_equal(
+            run_filter(sigs, np.zeros((3, 8), np.uint32), [-1, 0, -1]),
+            np.arange(SIG_ROW_TILE))
+
+    def test_slacks_share_one_program(self):
+        # Slacks are operands: two slack sets at the same Q_pad run the
+        # same compiled program.
+        rng = np.random.default_rng(8)
+        sigs = rng.integers(0, 2**32, (8, SIG_ROW_TILE), dtype=np.uint32)
+        qsig = rng.integers(0, 2**32, (5, 8), dtype=np.uint32)
+        run_filter(sigs, qsig, [4] * 5)
+        n0 = filter_qgram._cache_size()
+        for slacks in ([8] * 5, [12, 0, -1, 3, 30]):
+            np.testing.assert_array_equal(
+                run_filter(sigs, qsig, slacks),
+                np.flatnonzero(union_ref(sigs, qsig, slacks)))
+        assert filter_qgram._cache_size() == n0
+
+    def test_sharded_flags_decode_to_logical_rows(self):
+        # Two shard blocks of the cyclic layout, each its own dispatch:
+        # slot j of block s is logical row j * 2 + s.
+        blocks = [edge_group(4, SIG_ROW_TILE * 2, seed=30 + s)[0]
+                  for s in range(2)]
+        _, qsig, slacks = edge_group(4, SIG_ROW_TILE * 2, seed=30)
+        q, sl = pattern_operands(qsig, slacks)
+        flags = np.concatenate([np.asarray(filter_qgram(
+            b, q, sl, interpret=True)) for b in blocks], axis=1)
+        want = np.sort(np.concatenate([
+            np.flatnonzero(union_ref(b, qsig, slacks)) * 2 + s
+            for s, b in enumerate(blocks)]))
+        np.testing.assert_array_equal(survivor_rows(flags, 8, 2), want)
+
+    @pytest.mark.parametrize("Wb", [1, 8, 16, 128])
+    def test_vmem_blocks_are_bounded_at_any_group_size(self, Wb):
+        # The pattern block is fixed by Wb, not by Q: a group of any size
+        # streams through the same VMEM, and the row tile takes only what
+        # the pattern block leaves.
+        from repro.kernels.filter_qgram import (
+            _PATTERN_BUDGET, PATTERN_TILE, flag_tile, pattern_block)
+        from repro.kernels.tiling import VMEM_BLOCK_BUDGET, lane_bytes
+        pb = pattern_block(Wb)
+        slab = lane_bytes(1) * (-(-Wb // 8) * 8 + 8)
+        assert pb * slab <= _PATTERN_BUDGET or pb == PATTERN_TILE
+        assert Wb > 8 or pb == 64
+        tile = flag_tile(FILTER_BLOCK_ROWS * 4, Wb)
+        assert tile * (-(-Wb // 8) * 8 * 4 + 8) <= (
+            VMEM_BLOCK_BUDGET - _PATTERN_BUDGET) or tile == SIG_ROW_TILE
+
+    def test_planner_prices_pad_patterns(self):
+        # A lone query runs eight patterns (seven pads); the price says so.
+        from repro.core.tech import TPU_V5E
+        from repro.match.planner import analytic_filter_seconds
+        r = TPU_V5E
+        R = 1 << 24
+        one = analytic_filter_seconds(r, R, 8, 1)
+        assert one == analytic_filter_seconds(r, R, 8, 8)
+        assert one < analytic_filter_seconds(r, R, 8, 9)
 
     def test_kernel_rejects_unpadded_rows(self):
+        q, s = pattern_operands(np.zeros((1, 8), np.uint32), [0])
         with pytest.raises(ValueError, match="padded"):
-            filter_qgram(np.zeros((7, 8), np.uint32),
-                         np.zeros((1, 8), np.uint32), slack=0,
-                         interpret=True)
+            filter_qgram(np.zeros((8, 7), np.uint32), q, s, interpret=True)
+
+    def test_padded_rows_keep_the_grid_coarse(self):
+        assert padded_rows(48) == SIG_ROW_TILE
+        assert padded_rows(1585712) % FILTER_BLOCK_ROWS == 0
+        assert padded_rows(1585712) - 1585712 < FILTER_BLOCK_ROWS
+        assert padded_rows(3000) == 4096
 
 
 class TestIndexResidency:
@@ -133,7 +263,7 @@ class TestIndexResidency:
         eng.corpus.append_rows(new)
         assert ix.sig_pack_count == 1         # no repack
         assert ix.row_update_count == 3
-        got = np.asarray(ix.signatures())[R0:R0 + 3]
+        got = np.asarray(ix.signatures())[:, R0:R0 + 3].T
         want, _ = row_signatures(new, ix.q, ix.n_bits)
         np.testing.assert_array_equal(got, want)
 
@@ -143,7 +273,7 @@ class TestIndexResidency:
         ix.signatures()
         new = rng.integers(0, 4, (1, F), np.uint8)
         eng.corpus.set_rows(5, new)
-        got = np.asarray(ix.signatures())[5]
+        got = np.asarray(ix.signatures())[:, 5]
         want, _ = row_signatures(new, ix.q, ix.n_bits)
         np.testing.assert_array_equal(got, want[0])
 
@@ -151,11 +281,11 @@ class TestIndexResidency:
         rng, frags, eng = make_engine(seed=5)
         ix = eng.index
         ix.signatures()
-        rows0 = ix._sigs.shape[0]
+        rows0 = ix._sigs.shape[1]
         while eng.corpus.capacity_padded <= rows0:   # force a device extend
             eng.corpus.append_rows(rng.integers(0, 4, (32, F), np.uint8))
-        assert ix._sigs.shape[0] >= ix._rows_padded
-        assert ix._sigs.shape[0] % FILTER_ROW_TILE == 0
+        assert ix._sigs.shape[1] >= ix._rows_padded
+        assert ix._sigs.shape[1] % SIG_ROW_TILE == 0
         assert ix.sig_pack_count == 1
 
     def test_invalidate_drops_form(self):
@@ -474,6 +604,37 @@ class TestServiceFilterRouting:
             want = eng.match(MatchQuery.exact(
                 p, reduction="threshold", threshold=THR, filter=False))
             np.testing.assert_array_equal(t.result.hits, want.hits)
+
+    def test_coalesced_group_of_16_is_one_filter_dispatch(self):
+        rng, pat, eng, svc = self.make_service(44)
+        pats = np.stack([pat] + [rng.integers(0, 4, P, np.uint8)
+                                 for _ in range(15)])
+        thrs = [THR - (i % 3) for i in range(16)]
+        counters = eng.obs.metrics.counters
+        tickets = [svc.submit(MatchQuery.exact(
+            p, reduction="threshold", threshold=t, filter=True))
+            for p, t in zip(pats, thrs)]
+        svc.flush()
+        assert svc.stats.n_coalesced_launches == 1
+        assert counters["filter.dispatches"].value == 1
+        assert counters["filter.patterns"].value == 16
+        # The per-read reference union over the live rows' signatures.
+        ops = build_query_filter((np.uint8(1) << pats).astype(np.uint8),
+                                 thrs, eng.index.q, eng.index.n_bits)
+        sigs, _ = row_signatures(eng.corpus.fragments, eng.index.q,
+                                 eng.index.n_bits)
+        want = np.zeros(len(sigs), np.int32)
+        for q, slack in zip(ops.qsig_words, ops.slacks):
+            want |= filter_qgram_ref(sigs, q[None, :], slack)
+        for t in tickets:
+            np.testing.assert_array_equal(t.result.survivor_rows,
+                                          np.flatnonzero(want))
+        # A lone query is one dispatch of one pattern.
+        svc.submit(MatchQuery.exact(pats[2], reduction="threshold",
+                                    threshold=THR, filter=True))
+        svc.flush()
+        assert counters["filter.dispatches"].value == 2
+        assert counters["filter.patterns"].value == 17
 
     def test_per_tick_and_cache_stats(self):
         rng, pat, eng, svc = self.make_service(42)

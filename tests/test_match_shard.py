@@ -204,13 +204,23 @@ class TestFilteredPath:
         more[7, 5:21] = pat
         es.corpus.append_rows(more)
         pstr = "".join("ACGT"[c] for c in pat)
+        # A group of several patterns at different thresholds: one filter
+        # dispatch tests them all per shard.
+        group = np.stack([pat, frags[50, 3:19], more[40, 30:46],
+                          np.random.default_rng(43).integers(0, 4, 16,
+                                                             np.uint8)])
         for q in (MatchQuery.exact(pat, reduction="threshold", threshold=13),
                   MatchQuery.iupac("N" + pstr[1:], reduction="threshold",
-                                   threshold=13)):
+                                   threshold=13),
+                  MatchQuery.exact(group, mode="batched",
+                                   reduction="threshold",
+                                   threshold=[14, 16, 15, 14])):
             filt = es.match(dataclasses.replace(q, filter=True))
             scan = es.match(dataclasses.replace(q, filter=False))
             np.testing.assert_array_equal(filt.hits, scan.hits)
             assert scan.plan.strategy == "scan"
+        assert filt.plan.strategy == "filter" and filt.plan.n_patterns == 4
+        assert {50, 240} <= set(filt.hits[:, 0].tolist())
 
     def test_sharded_filter_true_never_silent_scans(self, n_shards):
         # Regression (PR 6 satellite): before sharding-aware filtering,
