@@ -1,4 +1,5 @@
-"""On-chip benchmark of the match stack: cells, traffic, reference, readers.
+"""On-chip benchmark of the match stack: cells, kinds, traffic, reference,
+readers.
 
 ``python3 bench/run.py --workload <cell> --seed <n> --seconds <s>
 --trace <0|1>`` runs one cell of ``BENCHMARK.json`` on the TPU it finds.

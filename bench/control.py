@@ -5,11 +5,12 @@
 
 For each seed, in one process on the chip: a whole run of the cell (set-up,
 a window at the cell's own load, the reference check), then the control
-in the program's place: the reference's own answers for the same sampled
-requests, computed with scores rounded through float8 (e4m3), checked by
-the same comparison.  One JSON line per seed, then the summary: each
-number's lower reading (the largest the program gave) and upper reading
-(the smallest the control gave).  The benchmark's own runs never run it.
+in the program's place, checked by the same comparison (the kind's
+``control``; for read mapping, the reference's own answers for the same
+sampled requests, computed with scores rounded through float8, e4m3).
+One JSON line per seed, then the summary: each number's lower reading
+(the largest the program gave) and upper reading (the smallest the
+control gave).  The benchmark's own runs never run it.
 """
 
 from __future__ import annotations

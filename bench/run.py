@@ -4,12 +4,13 @@
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> \\
         --trace <0|1>
 
-The cell, its configuration and its traffic are found by name
-(``bench/spec.py``).  Set-up makes the corpus and the traffic from the
-seed, builds the deployment (resident corpus, q-gram index, engine,
-service) and warms the cell's own shapes; then the window runs for
-``--seconds``.  Afterwards the program's state is freed and the sampled
-answers are checked against the plain reference on the same chip.
+The cell, its configuration, its traffic and the configuration's kind of
+deployment are found by name (``bench/spec.py``).  The kind
+(``bench/kinds/<kind>.py``) makes the data and the traffic from the seed,
+builds the deployment (resident corpus, q-gram index, engine, service) and
+warms the cell's own shapes; then its clients run the window for
+``--seconds``.  Afterwards the program's state is freed and the kind checks
+the sampled answers against the plain reference on the same chip.
 
 With ``--trace 0`` the result carries the cell's end-to-end metrics; with
 ``--trace 1`` the window runs under the JAX profiler and the result
@@ -30,7 +31,6 @@ T_START = time.perf_counter()
 
 import argparse  # noqa: E402
 import contextlib  # noqa: E402
-import dataclasses  # noqa: E402
 import gc  # noqa: E402
 import json  # noqa: E402
 import statistics  # noqa: E402
@@ -42,9 +42,7 @@ if __package__ in (None, ""):
     # Run as a script: import the benchmark as the package ``bench``.
     sys.path[0] = str(Path(__file__).resolve().parents[1])
 
-import numpy as np  # noqa: E402
-
-from bench import check, drive, gen, spec, tracing  # noqa: E402
+from bench import drive, spec, tracing  # noqa: E402
 
 
 def log(msg: str) -> None:
@@ -118,38 +116,8 @@ def describe_plans(win: drive.Window) -> None:
             + f" launches={n}")
 
 
-@dataclasses.dataclass
-class Ready:
-    """A cell after set-up: the deployment, warmed, and its traffic."""
-
-    stack: drive.Stack
-    rows: np.ndarray
-    reads: object                    # the window's read stream
-    counter: CompileCounter
-
-
-def set_up(cs: dict, seed: int, trace: bool, devices) -> Ready:
-    """Corpus and reads from the seed, the deployment on ``devices``, and
-    warm-up of the cell's own shapes (``drive.warm_up``)."""
-    config, traffic = cs["config"], cs["traffic"]
-    t0 = time.perf_counter()
-    F, stride = config["fragment_chars"], config["row_stride"]
-    P, G = config["pattern_chars"], config["genome_bp"]
-    g = gen.genome(seed, G, F, stride)
-    rows = gen.fold(g, G, F, stride)
-    t_data = time.perf_counter()
-    counter = CompileCounter()
-    stack = drive.build_stack(config, rows, devices, trace)
-    t_deploy = time.perf_counter()
-    warm = drive.warm_up(stack, gen.read_stream(seed, g, G, P, traffic,
-                                                gen.WARMUP), traffic)
-    log(f"set-up: data {t_data - t0:.3f} s, deployment "
-        f"{t_deploy - t_data:.3f} s, warm-up "
-        f"{time.perf_counter() - t_deploy:.3f} s {warm}; {rows.shape[0]} "
-        f"rows x {F} chars on {stack.engine.n_shards} shard(s); "
-        f"{counter.n} backend compiles")
-    return Ready(stack, rows, gen.read_stream(seed, g, G, P, traffic,
-                                              gen.READS), counter)
+def is_correct(nums: dict) -> bool:
+    return all(v["value"] <= v["limit"] for v in nums.values())
 
 
 def run_cell(cs: dict, seed: int, seconds: float, trace: bool, devices,
@@ -157,26 +125,26 @@ def run_cell(cs: dict, seed: int, seconds: float, trace: bool, devices,
              t_start: float = None) -> dict:
     """Set up, run the window, check, and reduce one run of a cell.
 
-    ``control`` also checks the control's answers in the program's place
-    (``bench/control.py``).
+    The cell's kind (``cs["kind"]``, ``bench/spec.py``) sets up, drives
+    the window and checks; ``control`` also checks the control's answers
+    in the program's place (``bench/control.py``).
     """
     import jax
 
-    config, traffic = cs["config"], cs["traffic"]
+    kind = cs["kind"]
     t0 = time.perf_counter() if t_start is None else t_start
-    rd = set_up(cs, seed, trace, devices)
-    stack = rd.stack
-    sample = drive.Reservoir(traffic["check_sample"],
-                             gen.rng(seed, gen.SAMPLE))
+    counter = CompileCounter()
+    dep = kind.set_up(cs, seed, devices, trace)
+    log(f"set-up: {dep.note}; {counter.n} backend compiles")
+    stack = dep.stack
     stats0 = stats_of(stack.service)
     tmp = tempfile.TemporaryDirectory() if trace else None
     setup_s = time.perf_counter() - t0
-    rd.counter.mark()
+    counter.mark()
     with (tracing.capture(tmp.name) if trace
           else contextlib.nullcontext()):
-        win = drive.closed_loop(stack, rd.reads, traffic, seconds, sample,
-                                trace)
-    compiles = rd.counter.since
+        win = kind.window(dep, seconds, trace)
+    compiles = counter.since
     stats1 = stats_of(stack.service)
     delta = {k: stats1[k] - stats0[k] for k in STATS}
     peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
@@ -195,43 +163,34 @@ def run_cell(cs: dict, seed: int, seconds: float, trace: bool, devices,
     if not trace:
         for m in cs["end_to_end"]:
             name = m["name"]
-            if name == "setup_s":
-                v = setup_s
-            elif name == "queries_per_s":
-                v = win.completed / win.seconds
-            else:
-                raise spec.SpecError(f"no measurement for {name!r}")
+            v = setup_s if name == "setup_s" else kind.metric(name, win)
             metrics[name] = {"value": v, "unit": m["unit"]}
 
     # The spans the program recorded, by name, to name the idle gaps.
     spans = {sp.name for sp in stack.engine.obs.tracer.iter_spans()}
     # Free the program's state before the reference runs on the chip.
-    rows = rd.rows
-    del stack, rd
+    held = kind.release(dep)
+    del stack, dep
     gc.collect()
     t_ref = time.perf_counter()
-    ref = check.reference_answers(sample.items, rows)
-    nums = check.numbers(win, sample.items, ref)
-    log(f"reference: {len(sample.items)} sampled answers of "
-        f"{sample.seen} ({sum(s.cached for s in sample.items)} from the "
-        f"result cache) checked in {time.perf_counter() - t_ref:.3f} s")
-    out = {"correct": check.is_correct(nums), "attempted": win.attempted,
+    nums, note = kind.check(held, win)
+    log(f"reference: {note} checked in {time.perf_counter() - t_ref:.3f} s")
+    out = {"correct": is_correct(nums), "attempted": win.attempted,
            "failed": win.failed, "metrics": metrics,
            "device": {"platform": devices[0].platform,
                       "kind": devices[0].device_kind,
                       "count": len(jax.devices()),
                       "memory_peak_bytes": int(peak)}}
     if control:
-        ctl = check.control_samples(sample.items, rows)
-        out["control"] = check.numbers(win, ctl, ref)
+        out["control"] = kind.control(held, win)
     if trace:
         red = tracing.reduce(tracing.load(tmp.name),
-                             span_names=spans | drive.BENCH_SPANS,
+                             span_names=spans | set(kind.SPANS),
                              device_ids={d.id for d in devices})
         tmp.cleanup()
         ctx = {"trace": red, "window": win, "stats": delta,
-               "compiles": compiles, "config": config, "traffic": traffic,
-               "peaks": peaks}
+               "compiles": compiles, "config": cs["config"],
+               "traffic": cs["traffic"], "peaks": peaks}
         readers = spec.readers(cs["per_layer"])
         for m in cs["per_layer"]:
             v = readers[m["name"]](ctx)

@@ -82,9 +82,9 @@ def test_fault_is_not_correct(cell, fault, tree, monkeypatch, capsys):
 def test_control_is_not_correct(cell, tree):
     import jax
 
-    from bench import check, run
+    from bench import run
     cs = spec.load_cell(cell, tree)
     out = run.run_cell(cs, SEED, 1.0, False, jax.devices()[:1],
                        tiny.CPU_PEAKS, control=True)
     assert out["correct"] is True
-    assert check.is_correct(out["control"]) is False, out["control"]
+    assert run.is_correct(out["control"]) is False, out["control"]

@@ -98,7 +98,7 @@ def test_benchmark_alone_gives_no_result(tmp_path):
 def test_warm_up_reaches_every_survivor_bucket(tree):
     import jax
 
-    from bench import drive, gen, run
+    from bench import drive, gen
     cs = spec.load_cell("readmap.v2-b16", tree)
     config, traffic = cs["config"], cs["traffic"]
     F, stride = config["fragment_chars"], config["row_stride"]
@@ -107,7 +107,7 @@ def test_warm_up_reaches_every_survivor_bucket(tree):
     stack = drive.build_stack(config, gen.fold(g, G, F, stride),
                               jax.devices()[:1], False)
     lo, hi, tries = traffic["warm_survivors"]
-    seen = drive.warm_buckets(
+    seen = cs["kind"].warm_buckets(
         stack.service, gen.read_stream(SEED, g, G, P, traffic, gen.WARMUP),
         traffic, lo, hi, tries)
     assert set(seen) >= {8, 16, 32, 64}, seen

@@ -138,7 +138,8 @@ def tiny_stack(seed=2 ** 31 + 5):
     from bench import drive, gen
     from repro.match import MatchQuery
     real = spec.load_cell(tiny.CELLS[0])
-    config = dict(real["config"], **tiny.CONFIG_SIZES["readmap-chr1"])
+    config = dict(real["config"], **tiny.sizes("configs",
+                                               real["cell"]["config"]))
     F, stride = config["fragment_chars"], config["row_stride"]
     P, G = config["pattern_chars"], config["genome_bp"]
     g = gen.genome(seed, G, F, stride)

@@ -8,6 +8,7 @@ import re
 import pytest
 
 from bench import spec
+from bench.tests import tiny
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
@@ -96,3 +97,19 @@ def test_cells_load_by_name(cell):
 def test_unknown_cell_is_an_error():
     with pytest.raises(spec.SpecError, match="no workload"):
         spec.load_cell("no.such-cell")
+
+
+def test_every_config_and_mix_has_cpu_sizes():
+    """The tests run every cell at the sizes in ``bench/tests/sizes``."""
+    missing = tiny.missing()
+    assert not missing, "no CPU sizes: " + ", ".join(map(str, missing))
+
+
+def test_every_kind_keeps_the_contract():
+    for c in BENCH["configs"]:
+        cfg = json.loads((spec.ROOT / c["file"]).read_text())
+        mod = spec.kind(cfg["kind"])
+        assert all(n.startswith("bench.") for n in mod.SPANS)
+        for name in ("set_up", "window", "metric", "release", "check",
+                     "control"):
+            assert callable(getattr(mod, name)), (cfg["kind"], name)
